@@ -32,7 +32,8 @@ _CELL = """
     # Table 8 uses Mistral-7B; the reduced stand-in keeps its GQA shape but
     # needs a long context + real batch for KV reads to dominate weights
     cfg = reduced_config(get_config("internlm2-1.8b"))
-    mesh = jax.make_mesh((8 // tp, tp), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8 // tp, tp), ("data", "model"))
     m_c, batch = 8192, 32
     with jax.sharding.set_mesh(mesh):
         model, step, rules = ST.build_serve(cfg, mesh, impl="flash")
@@ -56,6 +57,9 @@ def _compile_cell(tp: int, naive: bool) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # a CPU compile by design: the child must not try to take a chip the
+    # parent may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     code = textwrap.dedent(_CELL.format(tp=tp, naive=naive))
     p = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=560, env=env)

@@ -21,7 +21,10 @@ def main():
     ap.add_argument("--arch", default="internlm2-1.8b")
     ap.add_argument("--shape", default="decode_32k")
     args = ap.parse_args()
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # dry runs compile on 512 host devices; JAX_PLATFORMS=cpu keeps the
+    # children off any chip
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     for flag in ([], ["--multi-pod"]):
         print(f"=== {'multi-pod (2x16x16)' if flag else 'single-pod (16x16)'} ===")
         subprocess.run(

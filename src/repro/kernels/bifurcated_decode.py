@@ -90,11 +90,18 @@ TPU mapping notes:
     would need its own grid axis (future work, irrelevant at paper scales).
   * during the final (decode) grid step the context block index is pinned to
     the previous block, so Pallas's revisiting rule skips the DMA.
+  * Mosaic requires the last two dims of every block to be divisible by
+    (8, 128) or to equal the array's. Per-token vectors (int8 scales,
+    ragged-tail and chunk biases) are read one row at a time, so the
+    wrappers give them a unit second-minor axis (a free reshape) and the
+    block spans it: ``(1, 1, pm)`` over ``(n, 1, pm)``.
 
 Validated on CPU in interpret mode against `ref.py` over a shape/dtype sweep
-(tests/test_kernels.py, tests/test_fused_decode.py); intended layout for
-deployment: K_c stored (g, m_c, hd) ("gmk", the framework default) so block
-DMA is contiguous and no per-layer transpose copy is needed.
+(tests/test_kernels.py, tests/test_fused_decode.py) and compiled with Mosaic
+for a described v5e at real widths (tests/test_tpu_compile.py); intended
+layout for deployment: K_c stored (g, m_c, hd) ("gmk", the framework
+default) so block DMA is contiguous and no per-layer transpose copy is
+needed.
 """
 from __future__ import annotations
 
@@ -282,9 +289,9 @@ def _fused_q8_kernel(
     q_ref,      # (1, rows, hd)
     k_ref,      # (1, block_m, hd) int8 — quantized context block
     v_ref,      # (1, block_m, hd) int8
-    ks_ref,     # (1, block_m) f32 — per-(token, head) K scales, logit scale
-                #   PRE-FOLDED at quantize time (no multiply by `scale` here)
-    vs_ref,     # (1, block_m) f32 — per-(token, head) V scales
+    ks_ref,     # (1, 1, block_m) f32 — per-(token, head) K scales, logit
+                #   scale PRE-FOLDED at quantize time (no multiply by `scale`)
+    vs_ref,     # (1, 1, block_m) f32 — per-(token, head) V scales
     kd_ref,     # (1, ld, hd) bf16 — ALL samples' decode keys, group-major
     vd_ref,     # (1, ld, hd)
     bias_ref,   # (1, ld) f32      — decode-slot mask bias (0 / NEG_INF)
@@ -323,12 +330,12 @@ def _fused_q8_kernel(
             q.astype(jnp.float32), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )                              # (rows, block_m) — raw q·K_q
-        s = s * ks_ref[...]            # fold s_k (logit scale pre-folded)
+        s = s * ks_ref[0]              # fold s_k (logit scale pre-folded)
 
         # mask the zero-padded K tail of the last block
         pos = i * block_m + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(pos < m_c, s, NEG_INF)
-        _online_update(s, v, acc_scr, m_scr, l_scr, p_scale=vs_ref[...])
+        _online_update(s, v, acc_scr, m_scr, l_scr, p_scale=vs_ref[0])
 
     @pl.when(i == nb)
     def _decode_arm_and_flush():
@@ -382,6 +389,9 @@ def fused_bifurcated_decode_q8(
         k_scale = jnp.pad(k_scale, ((0, 0), (0, pad)))
         v_scale = jnp.pad(v_scale, ((0, 0), (0, pad)))
     nb = k_ctx_q.shape[1] // block_m
+    # unit second-minor axes: Mosaic's block rule (module notes)
+    k_scale = k_scale[:, None, :]
+    v_scale = v_scale[:, None, :]
 
     ld = k_dec.shape[1]
     ld_pad = (-ld) % 128   # lane-align the decode tile
@@ -407,10 +417,10 @@ def fused_bifurcated_decode_q8(
                          lambda gi, i: (gi, jnp.minimum(i, nb - 1), 0)),
             pl.BlockSpec((1, block_m, hd),
                          lambda gi, i: (gi, jnp.minimum(i, nb - 1), 0)),
-            pl.BlockSpec((1, block_m),
-                         lambda gi, i: (gi, jnp.minimum(i, nb - 1))),
-            pl.BlockSpec((1, block_m),
-                         lambda gi, i: (gi, jnp.minimum(i, nb - 1))),
+            pl.BlockSpec((1, 1, block_m),
+                         lambda gi, i: (gi, 0, jnp.minimum(i, nb - 1))),
+            pl.BlockSpec((1, 1, block_m),
+                         lambda gi, i: (gi, 0, jnp.minimum(i, nb - 1))),
             pl.BlockSpec((1, ld_full, hd), lambda gi, i: (gi, 0, 0)),
             pl.BlockSpec((1, ld_full, hd), lambda gi, i: (gi, 0, 0)),
             pl.BlockSpec((1, ld_full), lambda gi, i: (0, 0)),
@@ -439,7 +449,7 @@ def _grouped_fused_kernel(
     k_ref,      # (1, 1, block_m, hd) — context block of group gi
     v_ref,      # (1, 1, block_m, hd)
     grp_ref,    # (rows, 128) i32 — lane-replicated row -> group assignment
-    cb_ref,     # (1, block_m) f32 — per-group ragged-tail bias (0 / NEG_INF)
+    cb_ref,     # (1, 1, block_m) f32 — per-group ragged-tail bias
     kd_ref,     # (1, ld, hd)      — ALL slots' decode keys, group-major
     vd_ref,     # (1, ld, hd)
     bias_ref,   # (1, ld) f32      — decode-slot mask bias (0 / NEG_INF)
@@ -482,7 +492,7 @@ def _grouped_fused_kernel(
         preferred_element_type=jnp.float32,
     ) * scale                          # (rows, block_m)
     # ragged per-group tail (0 / NEG_INF, covers the zero-padded capacity)
-    s = s + cb_ref[...]
+    s = s + cb_ref[0]
     # row -> group assignment: only rows decoding THIS prefix contribute
     assigned = grp_ref[:, :1] == gi    # (rows, 1)
     s = jnp.where(assigned, s, NEG_INF)
@@ -540,6 +550,8 @@ def grouped_fused_bifurcated_decode(
         ctx_bias = jnp.pad(ctx_bias, ((0, 0), (0, pad)),
                            constant_values=NEG_INF)
     nb = k_ctx.shape[2] // block_m
+    # unit second-minor axes: Mosaic's block rule (module notes)
+    ctx_bias = ctx_bias[:, None, :]
 
     ld = k_dec.shape[1]
     ld_pad = (-ld) % 128   # lane-align the decode tile
@@ -563,7 +575,7 @@ def grouped_fused_bifurcated_decode(
             pl.BlockSpec((1, 1, block_m, hd),
                          lambda gk, gi, i: (gi, gk, i, 0)),
             pl.BlockSpec((rows, 128), lambda gk, gi, i: (0, 0)),
-            pl.BlockSpec((1, block_m), lambda gk, gi, i: (gi, i)),
+            pl.BlockSpec((1, 1, block_m), lambda gk, gi, i: (gi, 0, i)),
             pl.BlockSpec((1, ld_full, hd), lambda gk, gi, i: (gk, 0, 0)),
             pl.BlockSpec((1, ld_full, hd), lambda gk, gi, i: (gk, 0, 0)),
             pl.BlockSpec((1, ld_full), lambda gk, gi, i: (0, 0)),
@@ -587,11 +599,11 @@ def _grouped_fused_q8_kernel(
     q_ref,      # (1, rows, hd)
     k_ref,      # (1, 1, block_m, hd) int8 — quantized context block
     v_ref,      # (1, 1, block_m, hd) int8
-    ks_ref,     # (1, 1, block_m) f32 — per-(token, head) K scales, logit
-                #   scale PRE-FOLDED at quantize time
-    vs_ref,     # (1, 1, block_m) f32
+    ks_ref,     # (1, 1, 1, block_m) f32 — per-(token, head) K scales,
+                #   logit scale PRE-FOLDED at quantize time
+    vs_ref,     # (1, 1, 1, block_m) f32
     grp_ref,    # (rows, 128) i32 — lane-replicated row -> group assignment
-    cb_ref,     # (1, block_m) f32 — per-group ragged-tail bias (0 / NEG_INF)
+    cb_ref,     # (1, 1, block_m) f32 — per-group ragged-tail bias
     kd_ref,     # (1, ld, hd) bf16 — ALL slots' decode keys, group-major
     vd_ref,     # (1, ld, hd)
     bias_ref,   # (1, ld) f32      — decode-slot mask bias (0 / NEG_INF)
@@ -625,11 +637,11 @@ def _grouped_fused_q8_kernel(
         q.astype(jnp.float32), k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                  # (rows, block_m) — raw q·K_q
-    s = s * ks_ref[0]                  # fold s_k (logit scale pre-folded)
-    s = s + cb_ref[...]                # ragged per-group tail
+    s = s * ks_ref[0, 0]               # fold s_k (logit scale pre-folded)
+    s = s + cb_ref[0]                  # ragged per-group tail
     assigned = grp_ref[:, :1] == gi    # (rows, 1)
     s = jnp.where(assigned, s, NEG_INF)
-    _online_update(s, v, acc_scr, m_scr, l_scr, p_scale=vs_ref[0])
+    _online_update(s, v, acc_scr, m_scr, l_scr, p_scale=vs_ref[0, 0])
 
     @pl.when((gi == n_groups - 1) & (i == nb - 1))
     def _decode_arm_and_flush():
@@ -682,6 +694,10 @@ def grouped_fused_bifurcated_decode_q8(
         ctx_bias = jnp.pad(ctx_bias, ((0, 0), (0, pad)),
                            constant_values=NEG_INF)
     nb = k_ctx_q.shape[2] // block_m
+    # unit second-minor axes: Mosaic's block rule (module notes)
+    ctx_bias = ctx_bias[:, None, :]
+    k_scale = k_scale[:, :, None, :]
+    v_scale = v_scale[:, :, None, :]
 
     ld = k_dec.shape[1]
     ld_pad = (-ld) % 128   # lane-align the decode tile
@@ -704,10 +720,12 @@ def grouped_fused_bifurcated_decode_q8(
                          lambda gk, gi, i: (gi, gk, i, 0)),
             pl.BlockSpec((1, 1, block_m, hd),
                          lambda gk, gi, i: (gi, gk, i, 0)),
-            pl.BlockSpec((1, 1, block_m), lambda gk, gi, i: (gi, gk, i)),
-            pl.BlockSpec((1, 1, block_m), lambda gk, gi, i: (gi, gk, i)),
+            pl.BlockSpec((1, 1, 1, block_m),
+                         lambda gk, gi, i: (gi, gk, 0, i)),
+            pl.BlockSpec((1, 1, 1, block_m),
+                         lambda gk, gi, i: (gi, gk, 0, i)),
             pl.BlockSpec((rows, 128), lambda gk, gi, i: (0, 0)),
-            pl.BlockSpec((1, block_m), lambda gk, gi, i: (gi, i)),
+            pl.BlockSpec((1, 1, block_m), lambda gk, gi, i: (gi, 0, i)),
             pl.BlockSpec((1, ld_full, hd), lambda gk, gi, i: (gk, 0, 0)),
             pl.BlockSpec((1, ld_full, hd), lambda gk, gi, i: (gk, 0, 0)),
             pl.BlockSpec((1, ld_full), lambda gk, gi, i: (0, 0)),
@@ -736,7 +754,7 @@ def _tree_fused_kernel(
     v_ref,      # (1, 1, block_m, hd)
     path_ref,   # (depth, rows, 128) i32 — lane-replicated row -> node id per
                 #   trie level (-1 = level unused by that row)
-    cb_ref,     # (1, block_m) f32 — per-node ragged-tail bias (0 / NEG_INF)
+    cb_ref,     # (1, 1, block_m) f32 — per-node ragged-tail bias
     kd_ref,     # (1, ld, hd)      — ALL slots' decode keys, group-major
     vd_ref,     # (1, ld, hd)
     bias_ref,   # (1, ld) f32      — decode-slot mask bias (0 / NEG_INF)
@@ -783,7 +801,7 @@ def _tree_fused_kernel(
         preferred_element_type=jnp.float32,
     ) * scale                          # (rows, block_m)
     # ragged per-node tail (0 / NEG_INF, covers the zero-padded capacity)
-    s = s + cb_ref[...]
+    s = s + cb_ref[0]
     # path membership: a row contributes iff node ni sits on its path at
     # ANY level (unrolled over the static depth; -1 never matches).
     assigned = path_ref[0][:, :1] == ni   # (rows, 1)
@@ -849,6 +867,8 @@ def tree_fused_bifurcated_decode(
         ctx_bias = jnp.pad(ctx_bias, ((0, 0), (0, pad)),
                            constant_values=NEG_INF)
     nb = k_ctx.shape[2] // block_m
+    # unit second-minor axes: Mosaic's block rule (module notes)
+    ctx_bias = ctx_bias[:, None, :]
 
     ld = k_dec.shape[1]
     ld_pad = (-ld) % 128   # lane-align the decode tile
@@ -872,7 +892,7 @@ def tree_fused_bifurcated_decode(
             pl.BlockSpec((1, 1, block_m, hd),
                          lambda gk, ni, i: (ni, gk, i, 0)),
             pl.BlockSpec((depth, rows, 128), lambda gk, ni, i: (0, 0, 0)),
-            pl.BlockSpec((1, block_m), lambda gk, ni, i: (ni, i)),
+            pl.BlockSpec((1, 1, block_m), lambda gk, ni, i: (ni, 0, i)),
             pl.BlockSpec((1, ld_full, hd), lambda gk, ni, i: (gk, 0, 0)),
             pl.BlockSpec((1, ld_full, hd), lambda gk, ni, i: (gk, 0, 0)),
             pl.BlockSpec((1, ld_full), lambda gk, ni, i: (0, 0)),
@@ -896,11 +916,11 @@ def _tree_fused_q8_kernel(
     q_ref,      # (1, rows, hd)
     k_ref,      # (1, 1, block_m, hd) int8 — quantized node context block
     v_ref,      # (1, 1, block_m, hd) int8
-    ks_ref,     # (1, 1, block_m) f32 — per-(token, head) K scales, logit
-                #   scale PRE-FOLDED at quantize time
-    vs_ref,     # (1, 1, block_m) f32
+    ks_ref,     # (1, 1, 1, block_m) f32 — per-(token, head) K scales,
+                #   logit scale PRE-FOLDED at quantize time
+    vs_ref,     # (1, 1, 1, block_m) f32
     path_ref,   # (depth, rows, 128) i32 — lane-replicated row -> node id
-    cb_ref,     # (1, block_m) f32 — per-node ragged-tail bias (0 / NEG_INF)
+    cb_ref,     # (1, 1, block_m) f32 — per-node ragged-tail bias
     kd_ref,     # (1, ld, hd) bf16 — ALL slots' decode keys, group-major
     vd_ref,     # (1, ld, hd)
     bias_ref,   # (1, ld) f32      — decode-slot mask bias (0 / NEG_INF)
@@ -935,13 +955,13 @@ def _tree_fused_q8_kernel(
         q.astype(jnp.float32), k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                  # (rows, block_m) — raw q·K_q
-    s = s * ks_ref[0]                  # fold s_k (logit scale pre-folded)
-    s = s + cb_ref[...]                # ragged per-node tail
+    s = s * ks_ref[0, 0]               # fold s_k (logit scale pre-folded)
+    s = s + cb_ref[0]                  # ragged per-node tail
     assigned = path_ref[0][:, :1] == ni   # (rows, 1)
     for lvl in range(1, depth):
         assigned |= path_ref[lvl][:, :1] == ni
     s = jnp.where(assigned, s, NEG_INF)
-    _online_update(s, v, acc_scr, m_scr, l_scr, p_scale=vs_ref[0])
+    _online_update(s, v, acc_scr, m_scr, l_scr, p_scale=vs_ref[0, 0])
 
     @pl.when((ni == n_nodes - 1) & (i == nb - 1))
     def _decode_arm_and_flush():
@@ -997,6 +1017,10 @@ def tree_fused_bifurcated_decode_q8(
         ctx_bias = jnp.pad(ctx_bias, ((0, 0), (0, pad)),
                            constant_values=NEG_INF)
     nb = k_ctx_q.shape[2] // block_m
+    # unit second-minor axes: Mosaic's block rule (module notes)
+    ctx_bias = ctx_bias[:, None, :]
+    k_scale = k_scale[:, :, None, :]
+    v_scale = v_scale[:, :, None, :]
 
     ld = k_dec.shape[1]
     ld_pad = (-ld) % 128   # lane-align the decode tile
@@ -1019,10 +1043,12 @@ def tree_fused_bifurcated_decode_q8(
                          lambda gk, ni, i: (ni, gk, i, 0)),
             pl.BlockSpec((1, 1, block_m, hd),
                          lambda gk, ni, i: (ni, gk, i, 0)),
-            pl.BlockSpec((1, 1, block_m), lambda gk, ni, i: (ni, gk, i)),
-            pl.BlockSpec((1, 1, block_m), lambda gk, ni, i: (ni, gk, i)),
+            pl.BlockSpec((1, 1, 1, block_m),
+                         lambda gk, ni, i: (ni, gk, 0, i)),
+            pl.BlockSpec((1, 1, 1, block_m),
+                         lambda gk, ni, i: (ni, gk, 0, i)),
             pl.BlockSpec((depth, rows, 128), lambda gk, ni, i: (0, 0, 0)),
-            pl.BlockSpec((1, block_m), lambda gk, ni, i: (ni, i)),
+            pl.BlockSpec((1, 1, block_m), lambda gk, ni, i: (ni, 0, i)),
             pl.BlockSpec((1, ld_full, hd), lambda gk, ni, i: (gk, 0, 0)),
             pl.BlockSpec((1, ld_full, hd), lambda gk, ni, i: (gk, 0, 0)),
             pl.BlockSpec((1, ld_full), lambda gk, ni, i: (0, 0)),
@@ -1057,7 +1083,7 @@ def _paged_fused_kernel(
     v_ref,      # (1, 1, pm, hd)
     path_ref,   # (depth, rows, 128) i32 — lane-replicated row -> segment id
                 #   per trie level (-1 = level unused by that row)
-    cb_ref,     # (1, pm) f32 — per-list-position ragged-tail bias
+    cb_ref,     # (1, 1, pm) f32 — per-list-position ragged-tail bias
     kd_ref,     # (1, ld, hd)      — ALL slots' decode keys, group-major
     vd_ref,     # (1, ld, hd)
     bias_ref,   # (1, ld) f32      — decode-slot mask bias (0 / NEG_INF)
@@ -1108,7 +1134,7 @@ def _paged_fused_kernel(
             preferred_element_type=jnp.float32,
         ) * scale                      # (rows, pm)
         # ragged per-segment tail (0 / NEG_INF, covers page-pad positions)
-        s = s + cb_ref[...]
+        s = s + cb_ref[0]
         # path membership against the segment OWNING this page (unrolled
         # over the static depth; -1 never matches) — same mask op sequence
         # as the dense tree kernel.
@@ -1173,6 +1199,8 @@ def paged_fused_bifurcated_decode(
     g, rows, hd = q.shape
     pm = k_pages.shape[2]
     max_pages = page_ids.shape[0]
+    # unit second-minor axes: Mosaic's block rule (module notes)
+    page_bias = page_bias[:, None, :]
 
     ld = k_dec.shape[1]
     ld_pad = (-ld) % 128   # lane-align the decode tile
@@ -1204,9 +1232,9 @@ def paged_fused_bifurcated_decode(
                          (pid[jnp.minimum(i, last)], gk, 0, 0)),
             pl.BlockSpec((depth, rows, 128),
                          lambda gk, i, pid, seg, nl: (0, 0, 0)),
-            pl.BlockSpec((1, pm),
+            pl.BlockSpec((1, 1, pm),
                          lambda gk, i, pid, seg, nl:
-                         (jnp.minimum(i, last), 0)),
+                         (jnp.minimum(i, last), 0, 0)),
             pl.BlockSpec((1, ld_full, hd),
                          lambda gk, i, pid, seg, nl: (gk, 0, 0)),
             pl.BlockSpec((1, ld_full, hd),
@@ -1242,11 +1270,11 @@ def _paged_fused_q8_kernel(
     q_ref,      # (1, rows, hd)
     k_ref,      # (1, 1, pm, hd) int8 — quantized pool page
     v_ref,      # (1, 1, pm, hd) int8
-    ks_ref,     # (1, 1, pm) f32 — per-(token, head) K scales, logit scale
-                #   PRE-FOLDED at quantize time
-    vs_ref,     # (1, 1, pm) f32
+    ks_ref,     # (1, 1, 1, pm) f32 — per-(token, head) K scales, logit
+                #   scale PRE-FOLDED at quantize time
+    vs_ref,     # (1, 1, 1, pm) f32
     path_ref,   # (depth, rows, 128) i32
-    cb_ref,     # (1, pm) f32
+    cb_ref,     # (1, 1, pm) f32
     kd_ref,     # (1, ld, hd) bf16
     vd_ref,     # (1, ld, hd)
     bias_ref,   # (1, ld) f32
@@ -1283,14 +1311,14 @@ def _paged_fused_q8_kernel(
             q.astype(jnp.float32), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )                              # (rows, pm) — raw q·K_q
-        s = s * ks_ref[0]              # fold s_k (logit scale pre-folded)
-        s = s + cb_ref[...]            # ragged per-segment tail
+        s = s * ks_ref[0, 0]           # fold s_k (logit scale pre-folded)
+        s = s + cb_ref[0]              # ragged per-segment tail
         seg = pseg_ref[i]
         assigned = path_ref[0][:, :1] == seg   # (rows, 1)
         for lvl in range(1, depth):
             assigned |= path_ref[lvl][:, :1] == seg
         s = jnp.where(assigned, s, NEG_INF)
-        _online_update(s, v, acc_scr, m_scr, l_scr, p_scale=vs_ref[0])
+        _online_update(s, v, acc_scr, m_scr, l_scr, p_scale=vs_ref[0, 0])
 
     @pl.when(i == n_ctx)
     def _decode_arm_and_flush():
@@ -1339,6 +1367,10 @@ def paged_fused_bifurcated_decode_q8(
     g, rows, hd = q.shape
     pm = k_pages_q.shape[2]
     max_pages = page_ids.shape[0]
+    # unit second-minor axes: Mosaic's block rule (module notes)
+    page_bias = page_bias[:, None, :]
+    k_scale_pages = k_scale_pages[:, :, None, :]
+    v_scale_pages = v_scale_pages[:, :, None, :]
 
     ld = k_dec.shape[1]
     ld_pad = (-ld) % 128   # lane-align the decode tile
@@ -1365,17 +1397,17 @@ def paged_fused_bifurcated_decode_q8(
             pl.BlockSpec((1, 1, pm, hd),
                          lambda gk, i, pid, seg, nl:
                          (pid[jnp.minimum(i, last)], gk, 0, 0)),
-            pl.BlockSpec((1, 1, pm),
+            pl.BlockSpec((1, 1, 1, pm),
                          lambda gk, i, pid, seg, nl:
-                         (pid[jnp.minimum(i, last)], gk, 0)),
-            pl.BlockSpec((1, 1, pm),
+                         (pid[jnp.minimum(i, last)], gk, 0, 0)),
+            pl.BlockSpec((1, 1, 1, pm),
                          lambda gk, i, pid, seg, nl:
-                         (pid[jnp.minimum(i, last)], gk, 0)),
+                         (pid[jnp.minimum(i, last)], gk, 0, 0)),
             pl.BlockSpec((depth, rows, 128),
                          lambda gk, i, pid, seg, nl: (0, 0, 0)),
-            pl.BlockSpec((1, pm),
+            pl.BlockSpec((1, 1, pm),
                          lambda gk, i, pid, seg, nl:
-                         (jnp.minimum(i, last), 0)),
+                         (jnp.minimum(i, last), 0, 0)),
             pl.BlockSpec((1, ld_full, hd),
                          lambda gk, i, pid, seg, nl: (gk, 0, 0)),
             pl.BlockSpec((1, ld_full, hd),
@@ -1493,7 +1525,7 @@ def _packed_fused_kernel(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale                      # (rows, pm)
-        s = s + eb_ref[...]            # ragged tail / chunk-length bias
+        s = s + eb_ref[0]              # ragged tail / chunk-length bias
         seg = seg_ref[i]
         assigned = path_ref[0][:, :1] == seg   # (rows, 1)
         for lvl in range(1, depth):
@@ -1554,12 +1586,12 @@ def _packed_specs(
     ]
     if q8:
         in_specs += [
-            pl.BlockSpec((1, 1, pm),
+            pl.BlockSpec((1, 1, 1, pm),
                          lambda gk, i, kn, sg, pd, fd, ps, ne:
-                         (pd[jnp.minimum(i, last)], gk, 0)),
-            pl.BlockSpec((1, 1, pm),
+                         (pd[jnp.minimum(i, last)], gk, 0, 0)),
+            pl.BlockSpec((1, 1, 1, pm),
                          lambda gk, i, kn, sg, pd, fd, ps, ne:
-                         (pd[jnp.minimum(i, last)], gk, 0)),
+                         (pd[jnp.minimum(i, last)], gk, 0, 0)),
         ]
     in_specs += [
         # fresh-tile walk: the symmetric pinned stream (bf16 either way).
@@ -1571,9 +1603,9 @@ def _packed_specs(
                      (fd[jnp.minimum(i, last)], gk, 0, 0)),
         pl.BlockSpec((depth, rows, 128),
                      lambda gk, i, kn, sg, pd, fd, ps, ne: (0, 0, 0)),
-        pl.BlockSpec((1, pm),
+        pl.BlockSpec((1, 1, pm),
                      lambda gk, i, kn, sg, pd, fd, ps, ne:
-                     (jnp.minimum(i, last), 0)),
+                     (jnp.minimum(i, last), 0, 0)),
         pl.BlockSpec((rows, 128),
                      lambda gk, i, kn, sg, pd, fd, ps, ne: (0, 0)),
         pl.BlockSpec((rows, 128),
@@ -1677,6 +1709,8 @@ def packed_fused_bifurcated_decode(
     g, rows, hd = q.shape
     pm = k_pages.shape[2]
     max_q = ent_kind.shape[0]
+    # unit second-minor axes: Mosaic's block rule (module notes)
+    ent_bias = ent_bias[:, None, :]
 
     ld_full = 0
     if not emit_partials:
@@ -1777,8 +1811,8 @@ def _packed_fused_q8_kernel(
         )                              # raw q·K — scale folded below
         # pages: per-token k scales with the logit scale pre-folded;
         # fresh bf16 tiles: the plain logit scale.
-        s = s * jnp.where(is_page, ks_ref[0], jnp.float32(scale))
-        s = s + eb_ref[...]
+        s = s * jnp.where(is_page, ks_ref[0, 0], jnp.float32(scale))
+        s = s + eb_ref[0]
         seg = seg_ref[i]
         assigned = path_ref[0][:, :1] == seg
         for lvl in range(1, depth):
@@ -1786,7 +1820,8 @@ def _packed_fused_q8_kernel(
         cols = pos_ref[i] + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         ok = assigned & (is_page | (cols <= rpos_ref[:, :1]))
         s = jnp.where(ok, s, NEG_INF)
-        p_scale = jnp.where(is_page, vs_ref[0], jnp.ones_like(vs_ref[0]))
+        p_scale = jnp.where(is_page, vs_ref[0, 0],
+                            jnp.ones_like(vs_ref[0, 0]))
         _online_update(s, v, acc_scr, m_scr, l_scr, p_scale=p_scale)
 
     @pl.when(i == n_ctx)
@@ -1848,6 +1883,10 @@ def packed_fused_bifurcated_decode_q8(
     g, rows, hd = q.shape
     pm = k_pages_q.shape[2]
     max_q = ent_kind.shape[0]
+    # unit second-minor axes: Mosaic's block rule (module notes)
+    ent_bias = ent_bias[:, None, :]
+    k_scale_pages = k_scale_pages[:, :, None, :]
+    v_scale_pages = v_scale_pages[:, :, None, :]
 
     ld_full = 0
     if not emit_partials:

@@ -14,9 +14,12 @@ Usage:
 
 Each invocation is one process: the 512-device host-platform override below
 must run before jax initializes, and ONLY here (tests/benches see 1 device).
+The compile targets those host devices, so the process pins JAX to the CPU
+and never takes an accelerator.
 """
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")

@@ -3,8 +3,11 @@
   PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \
       --batch 16 --context 512 --steps 32 [--no-bifurcated] [--kernel]
 
-CPU-scale by default (reduced config); --full lowers the production config
-(TPU deployment path).
+Reduced config by default; --full runs internlm2-1.8b (or --arch) at its
+published widths. The fused Pallas kernel (--kernel) compiles with Mosaic
+on a TPU and runs in interpret mode on the CPU (``JAX_PLATFORMS=cpu``).
+``chip_smoke.py`` at the repository root drives the full-width serving
+path on one chip and checks its output.
 """
 from __future__ import annotations
 
@@ -16,11 +19,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ServeConfig, get_config, reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.runtime.serve import ServeEngine, rank_by_mean_logprob
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
     ap.add_argument("--batch", type=int, default=8)

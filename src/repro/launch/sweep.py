@@ -51,6 +51,7 @@ def run_sweep(out_dir, archs, shapes, impl, multi_pod, timeout=1800,
             print(f"[run ] {name} ...", flush=True)
             env = dict(os.environ)
             env["PYTHONPATH"] = "src"
+            env["JAX_PLATFORMS"] = "cpu"   # dry runs compile on host devices
             try:
                 p = subprocess.run(cmd, capture_output=True, text=True,
                                    timeout=timeout, env=env)

@@ -73,8 +73,10 @@ class TransformerLM:
 
     # ---- shared pieces ----
     def _embed(self, params, tokens):
-        x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.bfloat16)
-        return x
+        # the activation dtype every layer follows (weights cast to it at
+        # use): bf16 for serving, float32 for a reference forward
+        return jnp.take(params["embed"], tokens, axis=0).astype(
+            jnp.dtype(self.cfg.dtype))
 
     def _unembed(self, params, x, rules):
         cfg = self.cfg

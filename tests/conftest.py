@@ -102,17 +102,17 @@ def build_page_pool(arrays, node_lens, page_m, *, perm_seed=0,
 # ---------------------------------------------------------------------------
 
 def collect_pallas_calls(jaxpr):
-    """All pallas_call eqns in a jaxpr, recursing into sub-jaxprs
-    (duck-typed: ClosedJaxpr has .jaxpr, raw Jaxpr has .eqns — the modules
-    moved across jax versions)."""
+    """All pallas_call eqns in a jaxpr, recursing into sub-jaxprs."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
     calls = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
             calls.append(eqn)
         for v in eqn.params.values():
-            if hasattr(v, "jaxpr"):
+            if isinstance(v, ClosedJaxpr):
                 calls += collect_pallas_calls(v.jaxpr)
-            elif hasattr(v, "eqns"):
+            elif isinstance(v, Jaxpr):
                 calls += collect_pallas_calls(v)
     return calls
 

@@ -116,12 +116,11 @@ def test_same_flops_structure():
     def naive(q, kc, vc, kd, vd):
         return reference(q, kc, vc, kd, vd)
 
-    def cost(compiled):
-        ca = compiled.cost_analysis()
-        return ca[0] if isinstance(ca, list) else ca  # some jax versions wrap per-device
+    def cost(fn):
+        return jax.jit(fn).lower(q, kc, vc, kd, vd).compile().cost_analysis()
 
-    c_bif = cost(jax.jit(bifurcated_attention).lower(q, kc, vc, kd, vd).compile())
-    c_ref = cost(jax.jit(naive).lower(q, kc, vc, kd, vd).compile())
+    c_bif = cost(bifurcated_attention)
+    c_ref = cost(naive)
     f_bif = c_bif["flops"]
     f_ref = c_ref["flops"]
     # identical GEMM flops; small bookkeeping differences allowed (<5%)

@@ -144,7 +144,8 @@ def test_forest_cache_pspec_tree_layout_aware(ctx_quant, layout):
     from repro.launch.steps import cache_pspec_tree
 
     fam = forest_cache_family(ctx_quant)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     spec = fam.spec(2, 2, 4, 64, 8, 2, 16, ctx_layout=layout)
     ps = cache_pspec_tree(mesh, spec)
     ctx_dim = 3 if layout == "gmk" else 2
@@ -176,7 +177,8 @@ def test_forest_decode_spmd_compiles_on_8_devices():
         from repro.models import get_model
 
         cfg = reduced_config(get_config("internlm2-1.8b"))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         out = {}
         with mesh:
             model = get_model(cfg)

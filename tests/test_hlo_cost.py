@@ -31,10 +31,7 @@ def test_scan_trip_count_correction():
     unrolled = jax.jit(f_unroll).lower(x, ws).compile()
 
     analytic = 2.0 * L * d * d * d  # L matmuls
-    ca = scanned.cost_analysis()
-    if isinstance(ca, list):  # some jax versions wrap per-device
-        ca = ca[0]
-    xla_scan = ca["flops"]
+    xla_scan = scanned.cost_analysis()["flops"]
     ours_scan = analyze(scanned.as_text())["flops"]
     ours_unroll = analyze(unrolled.as_text())["flops"]
 

@@ -431,7 +431,8 @@ def test_paged_cache_pspec_pool_head_axis(small_model):
     from repro.launch import specs as S, steps as ST
 
     cfg, model, _ = small_model
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     rep = jax.sharding.PartitionSpec()
     for quant in ("none", "int8"):
         io = S.paged_decode_cache_specs(
@@ -471,7 +472,8 @@ def test_paged_decode_spmd_compiles_on_8_devices():
         from repro.models import get_model
 
         cfg = reduced_config(get_config("internlm2-1.8b"))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         out = {}
         with mesh:
             model = get_model(cfg)

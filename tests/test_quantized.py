@@ -139,7 +139,8 @@ def test_quant_cache_pspec_tree_layout_aware():
     from jax.sharding import PartitionSpec as P
     from repro.launch.steps import cache_pspec_tree
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     for layout in ("gmk", "mgk"):
         spec = QuantBifurcatedCache.spec(2, 4, 32, 8, 2, 16,
                                          ctx_layout=layout)

@@ -66,7 +66,8 @@ def test_sharded_serve_step_compiles_on_8_device_mesh():
         from repro.launch.hlo_cost import analyze
 
         cfg = reduced_config(get_config("internlm2-1.8b"))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         with mesh:
             model, step, rules = ST.build_serve(cfg, mesh, impl="flash")
             params = S.param_specs(model)
@@ -102,7 +103,8 @@ def test_sharded_serve_step_compiles_with_int8_cache():
         from repro.launch import specs as S, steps as ST
 
         cfg = reduced_config(get_config("internlm2-1.8b"))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         sizes = {}
         with mesh:
             model, step, rules = ST.build_serve(cfg, mesh, impl="flash")
@@ -140,7 +142,8 @@ def test_sharded_train_step_runs_on_8_device_mesh():
         from repro.data import SyntheticLMDataset
 
         cfg = reduced_config(get_config("internlm2-1.8b"))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         tcfg = TrainConfig(global_batch=8, seq_len=32, remat="none",
                            warmup_steps=2, total_steps=10)
         with mesh:

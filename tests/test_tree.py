@@ -316,7 +316,8 @@ def test_tree_cache_pspec_tree_layout_aware(ctx_quant, layout):
     from repro.launch.steps import cache_pspec_tree
 
     fam = tree_cache_family(ctx_quant)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     spec = fam.spec(2, 3, 2, 4, 64, 8, 2, 16, ctx_layout=layout)
     ps = cache_pspec_tree(mesh, spec)
     ctx_dim = 3 if layout == "gmk" else 2
