@@ -168,11 +168,17 @@ def _prefix_economics(engine, state) -> dict:
     return ps
 
 
+def _pool_occupancy(engine, state) -> float:
+    occ = engine.occupancy(state)
+    return (occ["pages_total"] - occ["pages_free"]) / occ["pages_total"]
+
+
 def _soak_durable(model, cfg, params, sched, *, seed: int, fault_plan,
                   workdir: str, max_new_tokens: int = 6):
     """Replay one arrival schedule through a DurableFrontend, surviving
     every ``kill_process`` by recovering from snapshot + journal and
-    resuming mid-workload. Returns (dfe, prefix_econ, wall_seconds).
+    resuming mid-workload. Returns (dfe, prefix_econ, wall_seconds, pool
+    occupancy after each pump).
     Raises on any invariant violation — the soak's job is to prove there
     are none."""
     dfe = DurableFrontend(
@@ -185,6 +191,7 @@ def _soak_durable(model, cfg, params, sched, *, seed: int, fault_plan,
     total_rounds = len(sched)
     submitted_upto = 0   # schedule rounds whose arrivals are journaled
     pumps = 0
+    occ = []
     while dfe.fe.round < total_rounds or dfe.pending():
         pumps += 1
         assert pumps <= total_rounds + 400, "soak liveness failure"
@@ -206,6 +213,7 @@ def _soak_durable(model, cfg, params, sched, *, seed: int, fault_plan,
             submitted_upto = target
         try:
             dfe.pump(params)
+            occ.append(_pool_occupancy(dfe.fe.engine, dfe.state))
         except ProcessKilled:
             # the frontend just "died" between rounds: resurrect it from
             # disk and resume — the loop re-pumps from the recovered round
@@ -213,7 +221,7 @@ def _soak_durable(model, cfg, params, sched, *, seed: int, fault_plan,
     wall = time.perf_counter() - t0
     _check_terminal(dfe.fe.tickets, max_new_tokens)
     econ = _prefix_economics(dfe.fe.engine, dfe.state)
-    return dfe, econ, wall
+    return dfe, econ, wall, occ
 
 
 def _soak_plain(model, cfg, params, sched, *, seed: int,
@@ -222,12 +230,14 @@ def _soak_plain(model, cfg, params, sched, *, seed: int,
     ServeFrontend (no durability layer in the measured path). ``tcfg``
     selects the engine envelope (cached default vs evict-eager
     baseline); ``policy`` selects the admission policy for the A/B
-    (fifo vs sharing on the SAME seeded schedule)."""
+    (fifo vs sharing on the SAME seeded schedule). Returns (fe,
+    prefix_econ, wall_seconds, pool occupancy after each pump)."""
     engine = TreeServeEngine(model, cfg, TreeConfig(**(tcfg or TCFG)))
     fe = ServeFrontend(engine, queue_depth=32, stall_rounds=6,
                        policy=policy)
     state = fe.init_state()
     rng, prefixes = _prefixes(cfg, seed)
+    occ = []
     t0 = time.perf_counter()
     for evs in sched:
         for ev in evs:
@@ -239,19 +249,22 @@ def _soak_plain(model, cfg, params, sched, *, seed: int,
                       priority=ev["priority"],
                       deadline_rounds=ev["deadline"])
         state = fe.pump(params, state)
-    state = fe.drain(params, state, max_rounds=len(sched) + 400)
+        occ.append(_pool_occupancy(engine, state))
+    while any(not t.terminal for t in fe.tickets):
+        assert fe.round < len(sched) + 400, "soak liveness failure"
+        state = fe.pump(params, state)
+        occ.append(_pool_occupancy(engine, state))
     wall = time.perf_counter() - t0
     _check_terminal(fe.tickets, max_new_tokens)
     econ = _prefix_economics(engine, state)
-    return fe, econ, wall
+    return fe, econ, wall, occ
 
 
-def _summarize(fe: ServeFrontend, econ: dict, wall: float) -> dict:
+def _summarize(fe: ServeFrontend, econ: dict, wall: float,
+               occ: list) -> dict:
     m = fe.metrics()
     done = [t for t in fe.tickets if t.status == COMPLETED]
     tokens = sum(sum(len(tok) for tok in t.tokens) for t in done)
-    occ = [(o["pages_total"] - o["pages_free"]) / o["pages_total"]
-           for o in fe.occupancy_log]
     m.update(
         wall_s=round(wall, 3),
         admission_policy=fe.policy.name,
@@ -292,17 +305,17 @@ def run(report) -> dict:
     params = model.init(jax.random.PRNGKey(0))
 
     with tempfile.TemporaryDirectory(prefix="serve_soak_") as workdir:
-        dfe, econ_f, wall_fault = _soak_durable(
+        dfe, econ_f, wall_fault, occ_f = _soak_durable(
             model, cfg, params, sched, seed=seed, fault_plan=plan,
             workdir=workdir)
-    fe_clean, econ_c, wall_clean = _soak_plain(model, cfg, params, sched,
-                                               seed=seed)
+    fe_clean, econ_c, wall_clean, occ_c = _soak_plain(model, cfg, params,
+                                                      sched, seed=seed)
     # evict-eagerly baseline of the SAME schedule: its only reuse is
     # within-batch sharing between concurrently-live requests — the
     # persistent cache must strictly beat it on token-weighted reuse
     # (the cross-request revivals) and at least match its hit rate.
-    fe_eager, econ_e, wall_eager = _soak_plain(model, cfg, params, sched,
-                                               seed=seed, tcfg=TCFG_EAGER)
+    fe_eager, econ_e, wall_eager, occ_e = _soak_plain(
+        model, cfg, params, sched, seed=seed, tcfg=TCFG_EAGER)
     assert econ_c["token_reuse_rate"] > econ_e["token_reuse_rate"], (
         econ_c["token_reuse_rate"], econ_e["token_reuse_rate"])
     assert econ_c["hit_rate"] >= econ_e["hit_rate"], (
@@ -322,10 +335,10 @@ def run(report) -> dict:
     # (the slack lane's contract).
     ab_kw = dict(rate=0.9, burst_every=2, burst_size=8, zipf_a=1.1)
     ab_sched = _workload(seed, rounds, **ab_kw)
-    fe_fifo, econ_pf, wall_pf = _soak_plain(model, cfg, params, ab_sched,
-                                            seed=seed, policy="fifo")
-    fe_shar, econ_s, wall_shar = _soak_plain(model, cfg, params, ab_sched,
-                                             seed=seed, policy="sharing")
+    fe_fifo, econ_pf, wall_pf, occ_pf = _soak_plain(
+        model, cfg, params, ab_sched, seed=seed, policy="fifo")
+    fe_shar, econ_s, wall_shar, occ_s = _soak_plain(
+        model, cfg, params, ab_sched, seed=seed, policy="sharing")
     fifo_io = fe_fifo.metrics()["modelled_io"]
     shar_io = fe_shar.metrics()["modelled_io"]
     assert shar_io["ctx_bytes_per_step"] < fifo_io["ctx_bytes_per_step"], (
@@ -359,9 +372,10 @@ def run(report) -> dict:
                      "baseline vs sharing-policy admission A/B of the "
                      "same schedule."),
         },
-        "faulty": _summarize(dfe.fe, econ_f, wall_fault),
-        "fault_free": _summarize(fe_clean, econ_c, wall_clean),
-        "fault_free_evict_eager": _summarize(fe_eager, econ_e, wall_eager),
+        "faulty": _summarize(dfe.fe, econ_f, wall_fault, occ_f),
+        "fault_free": _summarize(fe_clean, econ_c, wall_clean, occ_c),
+        "fault_free_evict_eager": _summarize(fe_eager, econ_e, wall_eager,
+                                             occ_e),
         # the policy axis: fifo vs sharing on ONE contended seeded Zipf
         # schedule (full per-arm summaries below; this block is the
         # asserted comparison in one place)
@@ -384,8 +398,8 @@ def run(report) -> dict:
                 fifo_io["ctx_bytes_per_step"]
                 / max(shar_io["ctx_bytes_per_step"], 1), 4),
         },
-        "policy_ab_fifo": _summarize(fe_fifo, econ_pf, wall_pf),
-        "policy_ab_sharing": _summarize(fe_shar, econ_s, wall_shar),
+        "policy_ab_fifo": _summarize(fe_fifo, econ_pf, wall_pf, occ_pf),
+        "policy_ab_sharing": _summarize(fe_shar, econ_s, wall_shar, occ_s),
     }
     payload["faulty"]["durability"] = dict(dfe.stats)
     BENCH_JSON.write_text(json.dumps(payload, indent=2))
@@ -400,8 +414,8 @@ def run(report) -> dict:
            payload["faulty"]["counters"].get("audits_passed", 0))
     report("serve_soak/faulty_tokens_per_s",
            payload["faulty"]["tokens_per_s"])
-    p99 = payload["faulty"]["per_token_latency_s"]["p99"]
-    report("serve_soak/faulty_p99_token_latency_ms",
+    p99 = payload["faulty"]["ttft_s"]["p99"]
+    report("serve_soak/faulty_p99_ttft_ms",
            round(p99 * 1e3, 2) if p99 is not None else None)
     report("serve_soak/pool_occupancy_max",
            payload["faulty"]["pool_occupancy"]["max"])

@@ -61,6 +61,7 @@ from repro.core.errors import AllocatorCorruption, CapacityError
 from repro.runtime.fault_tolerance import Heartbeat
 from repro.runtime.faults import FaultKind, FaultPlan, ProcessKilled
 from repro.runtime.scheduler import make_policy
+from repro.runtime.telemetry import span, spanned
 
 
 # Ticket lifecycle states. PREEMPTED is a TRANSITION, not a state: a
@@ -102,6 +103,7 @@ class Ticket:
     admitted_round: Optional[int] = None
     finished_round: Optional[int] = None
     submit_wall: float = 0.0
+    first_token_wall: Optional[float] = None   # first tokens on the host
     finish_wall: Optional[float] = None
     tokens: Optional[List[np.ndarray]] = None    # per-sample, on completion
     logprobs: Optional[List[np.ndarray]] = None
@@ -115,16 +117,6 @@ class Ticket:
     @property
     def terminal(self) -> bool:
         return self.status in TERMINAL
-
-    def per_token_latency(self) -> Optional[float]:
-        """Wall seconds per emitted token, submit -> finish (reporting
-        only — scheduling never reads wall time)."""
-        if self.finish_wall is None or not self.tokens:
-            return None
-        n = sum(len(t) for t in self.tokens)
-        if n == 0:
-            return None
-        return (self.finish_wall - self.submit_wall) / n
 
 
 class ServeFrontend:
@@ -178,7 +170,6 @@ class ServeFrontend:
         self.round = 0
         self.tickets: List[Ticket] = []
         self.counters: Dict[str, int] = {}
-        self.occupancy_log: List[dict] = []
         # modelled per-step IO ledger (engine.step_io_bytes x decode
         # chunks): the bytes/step axis the admission-policy A/B is
         # judged on. Snapshot state — replayed rounds re-accumulate it
@@ -212,26 +203,28 @@ class ServeFrontend:
         ever fit). ``segments`` is a (1, m) token array or a list of them
         (trie path, outermost shared level first); ``deadline_rounds`` is
         relative to now, in scheduler rounds."""
-        if not isinstance(segments, (list, tuple)):
-            segments = [segments]
-        segments = [jnp.asarray(s) for s in segments]
-        t = Ticket(
-            tid=len(self.tickets), segments=segments, n_samples=n_samples,
-            max_new_tokens=(max_new_tokens if max_new_tokens is not None
-                            else self.default_max_new_tokens),
-            priority=priority,
-            deadline_round=(self.round + deadline_rounds
-                            if deadline_rounds is not None else None),
-            submitted_round=self.round, next_try=self.round,
-            submit_wall=time.perf_counter(),
-        )
-        self.tickets.append(t)
-        self._count("submitted")
-        why = self._infeasible_reason(t)
-        if why is not None:
-            self._reject(t, why)
-        elif len(self._queued()) > self.queue_depth:
-            self._reject(t, REASON_QUEUE_FULL)
+        with span("frontend.submit", tid=len(self.tickets)):
+            if not isinstance(segments, (list, tuple)):
+                segments = [segments]
+            segments = [jnp.asarray(s) for s in segments]
+            t = Ticket(
+                tid=len(self.tickets), segments=segments,
+                n_samples=n_samples,
+                max_new_tokens=(max_new_tokens if max_new_tokens is not None
+                                else self.default_max_new_tokens),
+                priority=priority,
+                deadline_round=(self.round + deadline_rounds
+                                if deadline_rounds is not None else None),
+                submitted_round=self.round, next_try=self.round,
+                submit_wall=time.perf_counter(),
+            )
+            self.tickets.append(t)
+            self._count("submitted")
+            why = self._infeasible_reason(t)
+            if why is not None:
+                self._reject(t, why)
+            elif len(self._queued()) > self.queue_depth:
+                self._reject(t, REASON_QUEUE_FULL)
         return t.tid
 
     def pump(self, params, state, decode_steps: Optional[int] = None):
@@ -241,28 +234,29 @@ class ServeFrontend:
         device state. Never raises on capacity — only on genuine
         invariant violations (``AllocatorCorruption``)."""
         self.round += 1
-        if self.heartbeat is not None:
-            self.heartbeat.beat(self.round)
-        state = self._inject_faults(state)
-        state = self._return_stolen_pages(state)
-        state = self._collect(state)
-        state = self._check_deadlines(state)
-        state = self._admit_pass(params, state)
-        state = self._expire_finished(state)
-        state = self._decode(params, state,
-                             decode_steps or self.decode_steps)
-        state = self._quarantine_corrupt(state)
-        state = self._expire_finished(state)
-        state = self._collect(state)
-        state = self._watchdog(params, state)
-        self.occupancy_log.append(
-            dict(self.engine.occupancy(state), round=self.round))
-        if self.audit_every_round:
-            # stolen (fault-held) pages are allocated but live outside the
-            # engine mirrors — declare them so reconciliation stays exact
-            stolen = [i for _, ids in self._stolen for i in ids]
-            self.engine.audit_state(state, extra_tracked=stolen)
-            self._count("audits_passed")
+        with span("frontend.pump", round=self.round):
+            if self.heartbeat is not None:
+                self.heartbeat.beat(self.round)
+            state = self._inject_faults(state)
+            state = self._return_stolen_pages(state)
+            state = self._collect(state)
+            state = self._check_deadlines(state)
+            state = self._admit_pass(params, state)
+            state = self._expire_finished(state)
+            state = self._decode(params, state,
+                                 decode_steps or self.decode_steps)
+            state = self._quarantine_corrupt(state)
+            state = self._expire_finished(state)
+            state = self._collect(state)
+            state = self._watchdog(params, state)
+            if self.audit_every_round:
+                # stolen (fault-held) pages are allocated but live outside
+                # the engine mirrors — declare them so reconciliation
+                # stays exact
+                stolen = [i for _, ids in self._stolen for i in ids]
+                with span("frontend.audit"):
+                    self.engine.audit_state(state, extra_tracked=stolen)
+                self._count("audits_passed")
         return state
 
     def drain(self, params, state, *, max_rounds: int = 1000,
@@ -290,8 +284,9 @@ class ServeFrontend:
             by_status[t.status] = by_status.get(t.status, 0) + 1
             if t.reason:
                 by_reason[t.reason] = by_reason.get(t.reason, 0) + 1
-        lat = [t.per_token_latency() for t in self.tickets]
-        lat = sorted(x for x in lat if x is not None)
+        ttft = sorted(t.first_token_wall - t.submit_wall
+                      for t in self.tickets
+                      if t.first_token_wall is not None)
         il = self.io_ledger
         return {
             "rounds": self.round,
@@ -300,9 +295,8 @@ class ServeFrontend:
             "rejections_by_reason": by_reason,
             "preemptions": sum(t.preemptions for t in self.tickets),
             "counters": dict(self.counters),
-            "per_token_latency_s": {
-                "p50": _pct(lat, 50), "p99": _pct(lat, 99),
-            },
+            # submit -> first tokens on the host (reporting only)
+            "ttft_s": {"p50": _pct(ttft, 50), "p99": _pct(ttft, 99)},
             "modelled_io": {
                 "decode_steps": il["steps"],
                 "ctx_bytes_per_step": (
@@ -329,7 +323,6 @@ class ServeFrontend:
             "round": self.round,
             "tickets": [_ticket_to_dict(t) for t in self.tickets],
             "counters": dict(self.counters),
-            "occupancy_log": list(self.occupancy_log),
             "retire_suppressed_until": self._retire_suppressed_until,
             "stolen": [[due, [int(i) for i in ids]]
                        for due, ids in self._stolen],
@@ -340,7 +333,6 @@ class ServeFrontend:
         self.round = int(d["round"])
         self.tickets = [_ticket_from_dict(x) for x in d["tickets"]]
         self.counters = dict(d["counters"])
-        self.occupancy_log = list(d["occupancy_log"])
         self._retire_suppressed_until = int(d["retire_suppressed_until"])
         self._stolen = [(int(due), list(ids)) for due, ids in d["stolen"]]
         self.io_ledger = {k: int(v)
@@ -409,18 +401,26 @@ class ServeFrontend:
         self._emit(ev="reject", tid=t.tid, round=self.round, reason=reason)
 
     def _engine_admit(self, params, state, t: Ticket):
-        if self._is_tree:
-            state, slots = self.engine.admit(params, state, t.segments,
-                                             t.n_samples)
-            # stable rid from the engine's monotonic counter — the request
-            # table is a compacted dict, NOT a dense history list
-            t.handle = self.engine.last_rid
-        else:
-            ctx = (t.segments[0] if len(t.segments) == 1
-                   else jnp.concatenate(t.segments, axis=1))
-            state, slots = self.engine.admit(params, state, ctx, t.n_samples)
-            t.handle = self.engine.slot_group[slots[0]]
+        with span("frontend.admit", tid=t.tid) as sp:
+            if self._is_tree:
+                state, slots = self.engine.admit(params, state, t.segments,
+                                                 t.n_samples)
+                # stable rid from the engine's monotonic counter — the
+                # request table is a compacted dict, NOT a dense history
+                # list
+                t.handle = self.engine.last_rid
+            else:
+                ctx = (t.segments[0] if len(t.segments) == 1
+                       else jnp.concatenate(t.segments, axis=1))
+                state, slots = self.engine.admit(params, state, ctx,
+                                                 t.n_samples)
+                t.handle = self.engine.slot_group[slots[0]]
+            sp.set(rid=int(t.handle))
         t.slots = list(slots)
+        if (t.first_token_wall is None
+                and any(self.engine.outputs[s] for s in t.slots)):
+            # admission sampled the first tokens (no prefill left pending)
+            t.first_token_wall = time.perf_counter()
         t.status = RUNNING
         t.admitted_round = self.round
         t.tokens_emitted = t.n_samples       # first token sampled at admit
@@ -434,6 +434,7 @@ class ServeFrontend:
                          if self._is_tree else [int(t.handle)]))
         return state
 
+    @spanned("frontend.admit_pass")
     def _admit_pass(self, params, state):
         """The admission ladder. Eligible queued tickets (backoff
         expired) try to admit in the order the POLICY chooses
@@ -530,6 +531,7 @@ class ServeFrontend:
                    fault=bool(fault))
         return state
 
+    @spanned("frontend.collect")
     def _collect(self, state):
         """Retirement + ticket finalization. A RUNNING ticket whose engine
         request/group has retired becomes COMPLETED (results gathered
@@ -585,6 +587,7 @@ class ServeFrontend:
                            n_tokens=sum(len(x) for x in t.tokens))
         return state
 
+    @spanned("frontend.deadlines")
     def _check_deadlines(self, state):
         for t in self.tickets:
             if t.deadline_round is None or self.round <= t.deadline_round:
@@ -600,6 +603,7 @@ class ServeFrontend:
                 self._count("deadline_cancels")
         return state
 
+    @spanned("frontend.expire")
     def _expire_finished(self, state):
         """Deactivate slots that have emitted their ticket's
         ``max_new_tokens`` (the continuous-batching analogue of
@@ -613,6 +617,7 @@ class ServeFrontend:
                         if active[s] and steps[s] >= t.max_new_tokens - 1)
         return self.engine.deactivate_slots(state, done)
 
+    @spanned("frontend.decode")
     def _decode(self, params, state, n_steps: int):
         """One decode chunk for the whole slot table, shortened so no live
         slot can overrun its decode arm (``DecodeCapacityExceeded`` is a
@@ -654,8 +659,11 @@ class ServeFrontend:
             self.io_ledger["steps"] += chunk
         state = self.engine.step_chunk(params, state, chunk)
         # progress accounting for the watchdog
+        now = time.perf_counter()
         for t in self._running():
             emitted = sum(len(self.engine.outputs[s]) for s in t.slots)
+            if emitted and t.first_token_wall is None:
+                t.first_token_wall = now
             if emitted > t.tokens_emitted:
                 t.tokens_emitted = emitted
                 t.last_progress_round = self.round
@@ -666,6 +674,7 @@ class ServeFrontend:
                          for s in range(self.engine.ecfg.slots)])
         return state
 
+    @spanned("frontend.quarantine")
     def _quarantine_corrupt(self, state):
         """KV-corruption quarantine: the engine's NaN/Inf sentinel flags
         slots whose decode output went non-finite (poisoned pool bytes).
@@ -693,6 +702,7 @@ class ServeFrontend:
         self.engine.corrupt_slots.clear()
         return state
 
+    @spanned("frontend.watchdog")
     def _watchdog(self, params, state):
         """Stuck-decode watchdog: a RUNNING ticket with no token progress
         for ``stall_rounds`` rounds means the pipeline is wedged — most

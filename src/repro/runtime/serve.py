@@ -56,6 +56,7 @@ from repro.core.errors import (
 )
 from repro.core.kv_cache import BifurcatedCache, DecodeCache
 from repro.core.policy import BifurcationPolicy
+from repro.runtime.telemetry import event, span, spanned
 
 
 def sample_tokens(key, logits, temperature: float, top_p: float):
@@ -415,7 +416,8 @@ class _SlotTableEngine:
         def step(s, _):
             return self._decode_one(params, s)
 
-        return jax.lax.scan(step, state, None, length=n_steps)
+        with jax.named_scope("scan_step"):
+            return jax.lax.scan(step, state, None, length=n_steps)
 
     def step_chunk(self, params, state: ForestState, n_steps: int):
         """Run ``n_steps`` decode steps for the whole slot table as ONE
@@ -429,19 +431,26 @@ class _SlotTableEngine:
         sit at different depths; the guard tracks the deepest live one.)"""
         import numpy as np
 
-        active = np.asarray(state.active)
-        if active.any():
-            deepest = int(np.asarray(state.cache.dec_lens)[active].max())
-            cap = state.cache.decode_capacity
-            if deepest + n_steps > cap:
-                raise DecodeCapacityExceeded(
-                    f"chunk of {n_steps} steps would overflow "
-                    f"decode_capacity={cap} (deepest live slot at "
-                    f"{deepest}); retire slots or shorten the chunk")
-        state, (toks, lps, emits) = self._chunk(params, state,
-                                                n_steps=n_steps)
-        self.decode_dispatches += 1
-        self._collect_emitted(toks, lps, emits)
+        with span("engine.scan", n_steps=n_steps) as sp:
+            with span("engine.inputs"):
+                active = np.asarray(state.active)
+                if active.any():
+                    deepest = int(
+                        np.asarray(state.cache.dec_lens)[active].max())
+                    cap = state.cache.decode_capacity
+                    if deepest + n_steps > cap:
+                        raise DecodeCapacityExceeded(
+                            f"chunk of {n_steps} steps would overflow "
+                            f"decode_capacity={cap} (deepest live slot at "
+                            f"{deepest}); retire slots or shorten the "
+                            f"chunk")
+            sp.set(decode_rows=int(active.sum()))
+            with span("engine.dispatch"):
+                state, (toks, lps, emits) = self._chunk(params, state,
+                                                        n_steps=n_steps)
+            self.decode_dispatches += 1
+            with span("engine.collect"):
+                self._collect_emitted(toks, lps, emits)
         return state
 
     def _collect_emitted(self, toks, lps, emits):
@@ -1151,6 +1160,7 @@ class TreeServeEngine(_SlotTableEngine):
                 total += self.node_len[parent] * per_tok
             n = parent
 
+    @spanned("engine.evict")
     def _evict_cached(self, state: ForestState, *, need_nodes: int = 0,
                       need_pages: int = 0, protect=()) -> ForestState:
         """Lazily evict cached nodes until ``need_nodes`` free node slots
@@ -1269,101 +1279,129 @@ class TreeServeEngine(_SlotTableEngine):
         over the path. Returns (state, slot_ids). EOS-at-step-0 retires a
         slot before it ever enters the decode loop, as in the forest
         engine."""
-        tcfg = self.tcfg
-        segments = [jnp.asarray(s) for s in segments]
-        if not 1 <= len(segments) <= tcfg.depth:
-            raise ValueError(
-                f"request path of {len(segments)} levels; engine depth "
-                f"is {tcfg.depth}")
-        cap = state.cache.node_capacity
-        for seg in segments:
-            if seg.shape[1] > cap:
-                # admission REJECTION (never truncate): the node envelope
-                # bounds any segment, dense or paged.
-                raise SegmentCapacityExceeded(
-                    f"segment of {seg.shape[1]} tokens > node capacity {cap}")
-        path, matched = self.match_prefix(segments)
-        new_segs = segments[matched:]
-        if new_segs:
-            # collision with an IN-FLIGHT packed prefill: the first new
-            # level's (parent, tokens) identity may already be reserved by
-            # a pending admission — it can be neither reused (KV not
-            # written) nor duplicated. Retryable: clears when the pending
-            # prefill's chunks land. (Deeper new levels hang off nodes
-            # created by THIS admission, so only the first can collide.)
-            key0 = (path[-1] if path else -1,
-                    tuple(int(t) for t in jax.device_get(new_segs[0])[0]))
-            nid0 = self.node_index.get(key0)
-            if nid0 is not None and nid0 in self.node_pending:
-                raise PrefillInFlight(
-                    f"trie level is being prefilled by a pending packed "
-                    f"admission (node {nid0}) — retry after its chunks "
-                    f"land")
-        if tcfg.step_mode == "packed" and new_segs:
-            return self._admit_packed(params, state, segments, n_samples,
-                                      path, matched)
-        if tcfg.prefix_cache and len(new_segs) > len(self.free_nodes()):
-            # node-slot pressure: lazily evict cached nodes (LRU,
-            # children-first). The matched path is protected — it is
-            # about to be revived by this very admission.
-            state = self._evict_cached(state, need_nodes=len(new_segs),
-                                       protect=path)
-        free_n = self.free_nodes()
-        free_s = self.free_slots(state)
-        if len(new_segs) > len(free_n):
-            raise SegmentsExhausted(
-                f"need {len(new_segs)} free trie nodes, have {len(free_n)}"
-                " — retire first")
-        if len(free_s) < n_samples:
-            raise SlotsExhausted(
-                f"need {n_samples} free slots, have {len(free_s)}")
-        if self.paged:
-            # paged admission gates on allocatable POOL PAGES, before any
-            # prefill work: reused ancestors cost zero new pages.
-            from repro.core.paged import pages_needed
+        with span("engine.admit") as sp:
+            tcfg = self.tcfg
+            segments = [jnp.asarray(s) for s in segments]
+            if not 1 <= len(segments) <= tcfg.depth:
+                raise ValueError(
+                    f"request path of {len(segments)} levels; engine "
+                    f"depth is {tcfg.depth}")
+            cap = state.cache.node_capacity
+            for seg in segments:
+                if seg.shape[1] > cap:
+                    # admission REJECTION (never truncate): the node
+                    # envelope bounds any segment, dense or paged.
+                    raise SegmentCapacityExceeded(
+                        f"segment of {seg.shape[1]} tokens > node "
+                        f"capacity {cap}")
+            with span("engine.match"):
+                path, matched = self.match_prefix(segments)
+                new_segs = segments[matched:]
+                if new_segs:
+                    # collision with an IN-FLIGHT packed prefill: the first
+                    # new level's (parent, tokens) identity may already be
+                    # reserved by a pending admission — it can be neither
+                    # reused (KV not written) nor duplicated. Retryable:
+                    # clears when the pending prefill's chunks land.
+                    # (Deeper new levels hang off nodes created by THIS
+                    # admission, so only the first can collide.)
+                    key0 = (path[-1] if path else -1,
+                            tuple(int(t) for t in
+                                  jax.device_get(new_segs[0])[0]))
+                    nid0 = self.node_index.get(key0)
+                    if nid0 is not None and nid0 in self.node_pending:
+                        raise PrefillInFlight(
+                            f"trie level is being prefilled by a pending "
+                            f"packed admission (node {nid0}) — retry "
+                            f"after its chunks land")
+            sp.set(reused_tokens=sum(int(s.shape[1])
+                                     for s in segments[:matched]),
+                   new_tokens=sum(int(s.shape[1]) for s in new_segs))
+            if tcfg.step_mode == "packed" and new_segs:
+                with span("engine.reserve"):
+                    state, slots = self._admit_packed(
+                        params, state, segments, n_samples, path, matched)
+            else:
+                state, slots = self._admit_now(params, state, segments,
+                                               n_samples, path, matched)
+            sp.set(rid=self.last_rid)
+        return state, slots
 
-            n_pg = sum(pages_needed(int(s.shape[1]), self.tcfg.page_size)
-                       for s in new_segs)
-            if tcfg.prefix_cache and n_pg > self.page_alloc.free_count():
-                # page pressure: same lazy eviction, the victim prefix
-                # planned by the allocator against its live free list.
-                state = self._evict_cached(state, need_pages=n_pg,
+    def _admit_now(self, params, state: ForestState, segments,
+                   n_samples: int, path, matched) -> tuple:
+        """Synchronous admission: the prefill runs now and the slots
+        activate with their first tokens sampled."""
+        tcfg = self.tcfg
+        new_segs = segments[matched:]
+        with span("engine.reserve"):
+            if tcfg.prefix_cache and len(new_segs) > len(self.free_nodes()):
+                # node-slot pressure: lazily evict cached nodes (LRU,
+                # children-first). The matched path is protected — it is
+                # about to be revived by this very admission.
+                state = self._evict_cached(state, need_nodes=len(new_segs),
                                            protect=path)
-            if n_pg > self.page_alloc.free_count():
-                raise PoolExhausted(
-                    f"request needs {n_pg} pool pages for "
-                    f"{len(new_segs)} new node(s), only "
-                    f"{self.page_alloc.free_count()} of {self.num_pages} "
-                    f"free — retire first")
-            # close the page-aliasing window BEFORE allocating: freed
-            # nodes' pages may be handed to this admission, so their stale
-            # table rows are cleared first — no pool page is ever
-            # referenced by two nodes. (After the rejection checks: a
-            # rejected admit mutates nothing.)
-            state = self.release_retired(state)
-        slots = free_s[:n_samples]
+            free_n = self.free_nodes()
+            free_s = self.free_slots(state)
+            if len(new_segs) > len(free_n):
+                raise SegmentsExhausted(
+                    f"need {len(new_segs)} free trie nodes, have "
+                    f"{len(free_n)} — retire first")
+            if len(free_s) < n_samples:
+                raise SlotsExhausted(
+                    f"need {n_samples} free slots, have {len(free_s)}")
+            if self.paged:
+                # paged admission gates on allocatable POOL PAGES, before
+                # any prefill work: reused ancestors cost zero new pages.
+                from repro.core.paged import pages_needed
+
+                n_pg = sum(pages_needed(int(s.shape[1]),
+                                        self.tcfg.page_size)
+                           for s in new_segs)
+                if (tcfg.prefix_cache
+                        and n_pg > self.page_alloc.free_count()):
+                    # page pressure: same lazy eviction, the victim prefix
+                    # planned by the allocator against its live free list.
+                    state = self._evict_cached(state, need_pages=n_pg,
+                                               protect=path)
+                if n_pg > self.page_alloc.free_count():
+                    raise PoolExhausted(
+                        f"request needs {n_pg} pool pages for "
+                        f"{len(new_segs)} new node(s), only "
+                        f"{self.page_alloc.free_count()} of "
+                        f"{self.num_pages} free — retire first")
+                # close the page-aliasing window BEFORE allocating: freed
+                # nodes' pages may be handed to this admission, so their
+                # stale table rows are cleared first — no pool page is
+                # ever referenced by two nodes. (After the rejection
+                # checks: a rejected admit mutates nothing.)
+                state = self.release_retired(state)
+            slots = free_s[:n_samples]
 
         total = sum(int(s.shape[1]) for s in segments)
         offset = sum(int(s.shape[1]) for s in segments[:matched])
         cut = 0
-        if tcfg.suffix_prefill and matched:
-            # SUFFIX-ONLY prefill: the matched ancestors' cached KV is
-            # the context arm; only the new levels' tokens run through
-            # the model — admission costs O(new tokens), not O(path). On
-            # a FULL-path match the last cached token re-runs as a
-            # 1-token suffix so the first-token logits stay defined
-            # (cut < total always; nothing is rewritten).
-            cut = min(offset, total - 1)
-            k_anc, v_anc = self._gather_path_kv(state, path, cut)
-            suffix = jnp.concatenate(segments, axis=1)[:, cut:]
-            logits0, cache1 = self.model.prefill_suffix(
-                params, suffix, k_anc, v_anc, self.rules, start=cut)
-        else:
-            # ONE prefill of the full concatenation: reused ancestors are
-            # recomputed (identical values — same tokens, same positions)
-            # but NOT rewritten; each new node gets its token-slice.
-            full = jnp.concatenate(segments, axis=1)
-            logits0, cache1 = self.model.prefill(params, full, self.rules)
+        with span("engine.prefill"), \
+                jax.named_scope("prefill"):
+            if tcfg.suffix_prefill and matched:
+                # SUFFIX-ONLY prefill: the matched ancestors' cached KV
+                # is the context arm; only the new levels' tokens run
+                # through the model — admission costs O(new tokens), not
+                # O(path). On a FULL-path match the last cached token
+                # re-runs as a 1-token suffix so the first-token logits
+                # stay defined (cut < total always; nothing is rewritten).
+                cut = min(offset, total - 1)
+                k_anc, v_anc = self._gather_path_kv(state, path, cut)
+                suffix = jnp.concatenate(segments, axis=1)[:, cut:]
+                logits0, cache1 = self.model.prefill_suffix(
+                    params, suffix, k_anc, v_anc, self.rules, start=cut)
+            else:
+                # ONE prefill of the full concatenation: reused ancestors
+                # are recomputed (identical values — same tokens, same
+                # positions) but NOT rewritten; each new node gets its
+                # token-slice.
+                full = jnp.concatenate(segments, axis=1)
+                logits0, cache1 = self.model.prefill(params, full,
+                                                     self.rules)
         cache = state.cache
         self.prefix_stats["admits"] += 1
         if matched == len(segments):
@@ -1377,29 +1415,31 @@ class TreeServeEngine(_SlotTableEngine):
         for seg in new_segs:
             nid = free_n.pop(0)
             m = int(seg.shape[1])
-            if self.paged:
-                from repro.core.paged import pages_needed
+            with span("engine.node_write"):
+                if self.paged:
+                    from repro.core.paged import pages_needed
 
-                ids = self.page_alloc.alloc(
-                    pages_needed(m, self.tcfg.page_size))
-                self.node_pages[nid] = ids
-                cache = cache.write_node(
-                    cache1.k[:, 0, offset - cut:offset - cut + m],
-                    cache1.v[:, 0, offset - cut:offset - cut + m], nid, ids)
-            else:
-                cache = cache.write_node(
-                    cache1.k[:, 0, offset - cut:offset - cut + m],
-                    cache1.v[:, 0, offset - cut:offset - cut + m], nid)
-            key = (parent, tuple(int(t) for t in
-                                 jax.device_get(seg)[0]))
-            self.node_index[key] = nid
-            self.node_key[nid] = key
-            self.node_live[nid] = True
-            self.node_len[nid] = m
-            # write-time integrity fingerprint (re-verified at snapshot
-            # load / audit_state on demand)
-            from repro.core.integrity import segment_checksum
-            self.seg_checksums[nid] = segment_checksum(cache, nid)
+                    ids = self.page_alloc.alloc(
+                        pages_needed(m, self.tcfg.page_size))
+                    self.node_pages[nid] = ids
+                    cache = cache.write_node(
+                        cache1.k[:, 0, offset - cut:offset - cut + m],
+                        cache1.v[:, 0, offset - cut:offset - cut + m], nid,
+                        ids)
+                else:
+                    cache = cache.write_node(
+                        cache1.k[:, 0, offset - cut:offset - cut + m],
+                        cache1.v[:, 0, offset - cut:offset - cut + m], nid)
+                key = (parent, tuple(int(t) for t in
+                                     jax.device_get(seg)[0]))
+                self.node_index[key] = nid
+                self.node_key[nid] = key
+                self.node_live[nid] = True
+                self.node_len[nid] = m
+                # write-time integrity fingerprint (re-verified at
+                # snapshot load / audit_state on demand)
+                from repro.core.integrity import segment_checksum
+                self.seg_checksums[nid] = segment_checksum(cache, nid)
             path.append(nid)
             parent = nid
             offset += m
@@ -1407,32 +1447,35 @@ class TreeServeEngine(_SlotTableEngine):
             self.node_refs[nid] += 1
             self.node_cached.pop(nid, None)  # revival: cached -> live
 
-        path_col = jnp.asarray(
-            path + [-1] * (tcfg.depth - len(path)), jnp.int32)
-        slot_ids = jnp.asarray(slots, jnp.int32)
-        slot_mask = jnp.zeros((tcfg.slots,), bool).at[slot_ids].set(True)
-        cache = cache.assign_paths(slot_mask, path_col)
+        with span("engine.activate"):
+            path_col = jnp.asarray(
+                path + [-1] * (tcfg.depth - len(path)), jnp.int32)
+            slot_ids = jnp.asarray(slots, jnp.int32)
+            slot_mask = jnp.zeros((tcfg.slots,), bool).at[slot_ids].set(
+                True)
+            cache = cache.assign_paths(slot_mask, path_col)
 
-        key, sub = jax.random.split(state.key)
-        tok, lp, live = self._sample_first(sub, logits0, n_samples)
+            key, sub = jax.random.split(state.key)
+            tok, lp, live = self._sample_first(sub, logits0, n_samples)
 
-        state = ForestState(
-            cache=cache,
-            tokens=state.tokens.at[slot_ids, 0].set(tok),
-            active=state.active.at[slot_ids].set(live),
-            steps=state.steps.at[slot_ids].set(0),
-            key=key,
-        )
-        rid = self.next_rid
-        self.next_rid += 1
-        self.last_rid = rid
-        self.requests[rid] = {"path": list(path), "slots": list(slots),
-                              "live": True}
-        for i, s in enumerate(slots):
-            self.slot_request[s] = rid
-            self.outputs[s] = [int(tok[i])]
-            self.logps[s] = [float(lp[i])]
-            self.corrupt_slots.discard(s)  # fresh request, fresh verdict
+            state = ForestState(
+                cache=cache,
+                tokens=state.tokens.at[slot_ids, 0].set(tok),
+                active=state.active.at[slot_ids].set(live),
+                steps=state.steps.at[slot_ids].set(0),
+                key=key,
+            )
+            rid = self.next_rid
+            self.next_rid += 1
+            self.last_rid = rid
+            self.requests[rid] = {"path": list(path), "slots": list(slots),
+                                  "live": True}
+            for i, s in enumerate(slots):
+                self.slot_request[s] = rid
+                self.outputs[s] = [int(tok[i])]
+                self.logps[s] = [float(lp[i])]
+                self.corrupt_slots.discard(s)  # fresh request, fresh verdict
+        event("request.first_token", rid=rid)
         # slot reuse may have dropped the last reference to a retired
         # request's table entry — compact it away now
         self._compact_requests()
@@ -1571,29 +1614,38 @@ class TreeServeEngine(_SlotTableEngine):
         cv = min(self._prefill_chunk(), m_node - pend["buf_len"])
         chunk = pend["suffix"][pend["done"]:pend["done"] + cv]
 
-        active = np.asarray(state.active)
-        if active.any():
-            deepest = int(np.asarray(state.cache.dec_lens)[active].max())
-            cap = state.cache.decode_capacity
-            if deepest + 1 > cap:
-                raise DecodeCapacityExceeded(
-                    f"packed step would overflow decode_capacity={cap} "
-                    f"(deepest live slot at {deepest}); retire slots "
-                    f"first")
-        if self.paged and self.tcfg.use_kernel:
-            state, out, logits_last = self._packed_step_kernel(
-                params, state, pend, chunk, cv)
-        else:
-            state, out, logits_last = self._packed_step_ref(
-                params, state, pend, chunk, cv)
-        self.decode_dispatches += 1
-        self._collect_emitted(*out)
-        pend["buf_len"] += cv
-        pend["done"] += cv
-        if pend["buf_len"] == m_node:
-            state = self._complete_node(state, pend, nid, m_node)
-        if pend["node_i"] == len(pend["new"]):
-            state = self._activate_pending(state, rid, logits_last)
+        with span("engine.packed_step", rid=rid, prefill_tokens=cv) as sp:
+            with span("engine.inputs"):
+                active = np.asarray(state.active)
+                if active.any():
+                    deepest = int(
+                        np.asarray(state.cache.dec_lens)[active].max())
+                    cap = state.cache.decode_capacity
+                    if deepest + 1 > cap:
+                        raise DecodeCapacityExceeded(
+                            f"packed step would overflow decode_capacity="
+                            f"{cap} (deepest live slot at {deepest}); "
+                            f"retire slots first")
+            sp.set(decode_rows=int(active.sum()))
+            if self.paged and self.tcfg.use_kernel:
+                state, out, logits_last = self._packed_step_kernel(
+                    params, state, pend, chunk, cv)
+            else:
+                with span("engine.dispatch"):
+                    state, out, logits_last = self._packed_step_ref(
+                        params, state, pend, chunk, cv)
+            self.decode_dispatches += 1
+            with span("engine.collect"):
+                self._collect_emitted(*out)
+            pend["buf_len"] += cv
+            pend["done"] += cv
+            if pend["buf_len"] == m_node:
+                with span("engine.node_write"):
+                    state = self._complete_node(state, pend, nid, m_node)
+            if pend["node_i"] == len(pend["new"]):
+                with span("engine.activate"):
+                    state = self._activate_pending(state, rid, logits_last)
+                event("request.first_token", rid=rid)
         return state
 
     def _packed_one_body(self, params, state: ForestState, k_fresh,
@@ -1607,13 +1659,14 @@ class TreeServeEngine(_SlotTableEngine):
         cp = chunk_tokens.shape[1]
         key, sub = jax.random.split(state.key)
         fresh_pos = fresh_start + buf_len + jnp.arange(cp, dtype=jnp.int32)
-        logits, logits_c, cache, k_fresh, v_fresh = \
-            self.model.decode_step_packed(
-                params, state.cache, state.tokens, chunk_tokens,
-                self.rules, k_fresh=k_fresh, v_fresh=v_fresh,
-                buf_len=buf_len, chunk_valid=chunk_valid,
-                fresh_start=fresh_start, fresh_pos=fresh_pos,
-                fresh_path=fresh_path)
+        with jax.named_scope("packed_step"):
+            logits, logits_c, cache, k_fresh, v_fresh = \
+                self.model.decode_step_packed(
+                    params, state.cache, state.tokens, chunk_tokens,
+                    self.rules, k_fresh=k_fresh, v_fresh=v_fresh,
+                    buf_len=buf_len, chunk_valid=chunk_valid,
+                    fresh_start=fresh_start, fresh_pos=fresh_pos,
+                    fresh_path=fresh_path)
         logits = logits[:, -1]
         sampled = sample_tokens(sub, logits, ecfg.temperature, ecfg.top_p)
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
@@ -1638,22 +1691,24 @@ class TreeServeEngine(_SlotTableEngine):
 
         tcfg, cfg = self.tcfg, self.cfg
         cp = self._prefill_chunk()
-        if pend["k_fresh"] is None:
-            shape = (cfg.n_layers, self.pages_per_node * tcfg.page_size,
-                     cfg.n_kv_heads_padded, cfg.kq_dim)
-            dtype = state.cache.k_dec.dtype
-            pend["k_fresh"] = jnp.zeros(shape, dtype)
-            pend["v_fresh"] = jnp.zeros(shape, dtype)
-        buf = np.zeros((1, cp), np.int32)
-        buf[0, :cv] = chunk
-        fpath = np.full((tcfg.depth,), -1, np.int32)
-        fpath[:len(pend["path"])] = pend["path"]
-        state, out, logits_last, pend["k_fresh"], pend["v_fresh"] = \
-            self._packed_one(
-                params, state, pend["k_fresh"], pend["v_fresh"],
-                jnp.asarray(buf), jnp.int32(pend["buf_len"]),
-                jnp.int32(cv), jnp.int32(pend["fresh_start"]),
-                jnp.asarray(fpath))
+        with span("engine.inputs"):
+            if pend["k_fresh"] is None:
+                shape = (cfg.n_layers, self.pages_per_node * tcfg.page_size,
+                         cfg.n_kv_heads_padded, cfg.kq_dim)
+                dtype = state.cache.k_dec.dtype
+                pend["k_fresh"] = jnp.zeros(shape, dtype)
+                pend["v_fresh"] = jnp.zeros(shape, dtype)
+            buf = np.zeros((1, cp), np.int32)
+            buf[0, :cv] = chunk
+            fpath = np.full((tcfg.depth,), -1, np.int32)
+            fpath[:len(pend["path"])] = pend["path"]
+            args = (jnp.asarray(buf), jnp.int32(pend["buf_len"]),
+                    jnp.int32(cv), jnp.int32(pend["fresh_start"]),
+                    jnp.asarray(fpath))
+        with span("engine.dispatch"):
+            state, out, logits_last, pend["k_fresh"], pend["v_fresh"] = \
+                self._packed_one(params, state, pend["k_fresh"],
+                                 pend["v_fresh"], *args)
         return state, out, logits_last
 
     def _packed_step_ref(self, params, state: ForestState, pend,
@@ -1773,6 +1828,7 @@ class TreeServeEngine(_SlotTableEngine):
         return state
 
     # ---- retire ----
+    @spanned("engine.retire")
     def retire_requests(self, state: ForestState, active=None):
         """Free every request whose slots have all gone inactive. Node
         refcounts drop along the retired paths; a node's segment (and its
@@ -1849,6 +1905,7 @@ class TreeServeEngine(_SlotTableEngine):
                     if not req["live"] and r not in referenced]:
             del self.requests[rid]
 
+    @spanned("engine.release")
     def release_retired(self, state: ForestState) -> ForestState:
         """Paged mode: clear the page-table rows of every freed trie node,
         structurally removing their pages from the decode kernels'
