@@ -52,7 +52,8 @@ technique eliminates b-fold. Eleven kernels live here:
   in-register masking). Single-prefix decoding is one segment with
   all-zero paths, the forest is depth == 1, the trie the full path table;
   on the same logical contents the output is bit-identical to the dense
-  kernels at ``page_m == block_m``.
+  kernels at ``page_m == block_m``. The bf16 kernel walks a block of kv
+  heads per grid step (``walk_head_block``), the q8 twin one head.
 
 ``packed_fused_bifurcated_decode`` / ``..._q8`` — the HETEROGENEOUS-STEP
   generalization (PackInfer / CoDec lineage): the scalar-prefetched
@@ -1069,6 +1070,103 @@ def tree_fused_bifurcated_decode_q8(
 # ---------------------------------------------------------------------------
 # Paged fused kernels: page-pool storage, DMA-eliding page-walk grid
 # ---------------------------------------------------------------------------
+#
+# Head-blocked walk (bf16 paged and packed kernels): one grid step takes one
+# page-list entry for a block of ``hb`` kv heads — the pool is head-major
+# inside a page, so the block's K (and V) is one contiguous DMA — and runs
+# the per-head op sequence once per head of the block. Every grid step has
+# a fixed cost, which dead list entries (past n_live) pay too, and a bigger
+# block amortizes it over more heads' work: on a v5e at the serving
+# benchmark's shapes one layer's paged call at 240 live pages fell from
+# 1.40 ms (grid (8, 513)) to 0.79 ms (grid (1, 513)).
+# ``hb`` is the largest divisor of g whose VMEM working set fits
+# WALK_VMEM_BUDGET; it is read off the shapes, never configured. The
+# decode-arm K/V stay in HBM and are copied in one head's slice at a time
+# (two slots: head h + 1's copy overlaps head h's arm), so the block size
+# does not scale the largest operands.
+
+WALK_VMEM_BUDGET = 48 * 2**20   # bytes; v5e has 128 MiB of VMEM per core
+_SCOPED_VMEM_DEFAULT = 16 * 2**20
+
+
+def walk_vmem_bytes(hb: int, *, rows: int, hd: int, pm: int, depth: int,
+                    ld: int, itemsize: int, fresh: bool = False,
+                    carry: bool = False, partials: bool = False) -> int:
+    """VMEM one head-blocked walk holds at ``hb`` kv heads: the pipelined
+    blocks twice (page K/V, the packed kernel's fresh K/V, q, out or the
+    raw partials, a carry, the path table and per-row/per-entry vectors),
+    the fp32 (acc, m, l) scratch once, two heads' decode-arm K/V slices,
+    and one head's (rows, ld) fp32 decode-arm logits and weights."""
+    stats = hb * rows * (hd + 2 * 128) * 4
+    ws = 2 * 2 * hb * pm * hd * itemsize * (2 if fresh else 1)
+    ws += 2 * hb * rows * hd * itemsize            # q
+    ws += stats                                    # scratch
+    ws += 2 * depth * rows * 128 * 4               # path table
+    ws += 2 * 8 * pm * 4                           # entry bias
+    if fresh:
+        ws += 2 * 2 * rows * 128 * 4               # row_pos, row_slot
+    if carry:
+        ws += 2 * stats
+    if partials:
+        ws += 2 * stats
+    else:
+        ws += 2 * hb * rows * hd * itemsize        # out
+        ws += 2 * 2 * ld * hd * itemsize           # decode-arm slices
+        ws += 2 * 8 * ld * 4                       # decode-arm bias
+        ws += 2 * rows * ld * 4                    # one head's arm temps
+    return ws
+
+
+def walk_head_block(g: int, **shapes) -> int:
+    """Kv heads per grid step: the largest divisor of ``g`` whose
+    ``walk_vmem_bytes`` fits ``WALK_VMEM_BUDGET`` (1 when none does)."""
+    for hb in range(g, 0, -1):
+        if g % hb == 0 and walk_vmem_bytes(hb, **shapes) <= WALK_VMEM_BUDGET:
+            return hb
+    return 1
+
+
+def _walk_compiler_params(hb: int, **shapes):
+    """Scoped-VMEM limit for a head-blocked walk, from its working set
+    (with room for Mosaic's own scratch); the default where that is
+    enough."""
+    need = walk_vmem_bytes(hb, **shapes) * 3 // 2
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=max(need, _SCOPED_VMEM_DEFAULT))
+
+
+def _decode_arm_copies(kd_hbm, vd_hbm, kd_buf, vd_buf, sem, head0):
+    """DMA descriptors moving kv head ``head0 + h``'s decode-arm K/V from
+    HBM into VMEM slot ``h % 2``; the same descriptor starts and waits."""
+    def copies(h):
+        slot = h % 2
+        return (
+            pltpu.make_async_copy(kd_hbm.at[head0 + h], kd_buf.at[slot],
+                                  sem.at[0, slot]),
+            pltpu.make_async_copy(vd_hbm.at[head0 + h], vd_buf.at[slot],
+                                  sem.at[1, slot]),
+        )
+    return copies
+
+
+def _decode_arm_heads(copies, kd_buf, vd_buf, hb):
+    """Yield (h, kd, vd) per head of the block: wait for head h's slices,
+    start head h + 1's into the other slot, hand h's over. Head 0's copy
+    is started by the walk's first grid step."""
+    for h in range(hb):
+        for c in copies(h):
+            c.wait()
+        if h + 1 < hb:
+            for c in copies(h + 1):
+                c.start()
+        yield h, kd_buf[h % 2], vd_buf[h % 2]
+
+
+def _decode_arm_scratch(ld_full, hd, dtype):
+    return [pltpu.VMEM((2, ld_full, hd), dtype),
+            pltpu.VMEM((2, ld_full, hd), dtype),
+            pltpu.SemaphoreType.DMA((2, 2))]
+
 
 def _paged_fused_kernel(
     # scalar-prefetch refs (SMEM, available to the index maps too):
@@ -1078,32 +1176,37 @@ def _paged_fused_kernel(
     pseg_ref,   # (max_pages,) i32 — segment id owning the page at pos i
     nlive_ref,  # (1,) i32 — number of live pages (page-walk early exit)
     # tensor operands:
-    q_ref,      # (1, rows, hd)
-    k_ref,      # (1, 1, pm, hd) — ONE page of the pool (block (page, gk))
-    v_ref,      # (1, 1, pm, hd)
+    q_ref,      # (hb, rows, hd)
+    k_ref,      # (1, hb, pm, hd) — ONE page of the pool for hb kv heads
+    v_ref,      # (1, hb, pm, hd)
     path_ref,   # (depth, rows, 128) i32 — lane-replicated row -> segment id
                 #   per trie level (-1 = level unused by that row)
     cb_ref,     # (1, 1, pm) f32 — per-list-position ragged-tail bias
-    kd_ref,     # (1, ld, hd)      — ALL slots' decode keys, group-major
-    vd_ref,     # (1, ld, hd)
+    kd_hbm,     # (g, ld, hd) in HBM — ALL slots' decode keys, group-major
+    vd_hbm,     # (g, ld, hd) in HBM
     bias_ref,   # (1, ld) f32      — decode-slot mask bias (0 / NEG_INF)
-    out_ref,    # out: (1, rows, hd) — normalized attention output
-    acc_scr,    # scratch (rows, hd) f32
-    m_scr,      # scratch (rows, 128) f32
-    l_scr,      # scratch (rows, 128) f32
+    out_ref,    # out: (hb, rows, hd) — normalized attention output
+    acc_scr,    # scratch (hb, rows, hd) f32
+    m_scr,      # scratch (hb, rows, 128) f32
+    l_scr,      # scratch (hb, rows, 128) f32
+    kd_buf,     # scratch (2, ld, hd) — two heads' decode keys
+    vd_buf,     # scratch (2, ld, hd)
+    dma_sem,    # DMA semaphores (2, 2): (k / v, slot)
     *,
     scale: float,
     c_d: int,
     pn: int,
     depth: int,
+    hb: int,
 ):
     """Paged generalization of ``_tree_fused_kernel``: the segment×block
     grid collapses into ONE page-walk axis driven by a scalar-prefetched
-    live-page list. Grid step i loads pool page ``pid_ref[i]`` — the index
-    map reads the prefetched list, so which HBM bytes move is runtime DATA
-    — and the per-block op sequence (scale, ragged-tail bias, path-
-    membership mask, online update) is IDENTICAL to the dense tree kernel,
-    which is what makes fully-populated pages bit-exact against it.
+    live-page list. Grid step i loads pool page ``pid_ref[i]`` for a block
+    of ``hb`` kv heads — the index map reads the prefetched list, so which
+    HBM bytes move is runtime DATA — and each head's op sequence (scale,
+    ragged-tail bias, path-membership mask, online update) is IDENTICAL to
+    the dense tree kernel, which is what makes fully-populated pages
+    bit-exact against it.
 
     DMA elision is structural, not masked: list entries past ``n_live``
     repeat the last live page (same block index ⇒ the revisiting rule skips
@@ -1116,50 +1219,54 @@ def _paged_fused_kernel(
     bit-identical with or without it."""
     i = pl.program_id(1)
     n_ctx = pl.num_programs(1) - 1   # page-walk steps; last = decode arm
+    copies = _decode_arm_copies(kd_hbm, vd_hbm, kd_buf, vd_buf, dma_sem,
+                                pl.program_id(0) * hb)
 
     @pl.when(i == 0)
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
-
-    q = q_ref[0]                      # (rows, hd)
+        for c in copies(0):
+            c.start()
 
     @pl.when((i < n_ctx) & (i < nlive_ref[0]))
     def _context_page():
-        k = k_ref[0, 0]               # (pm, hd)
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                      # (rows, pm)
-        # ragged per-segment tail (0 / NEG_INF, covers page-pad positions)
-        s = s + cb_ref[0]
         # path membership against the segment OWNING this page (unrolled
         # over the static depth; -1 never matches) — same mask op sequence
-        # as the dense tree kernel.
+        # as the dense tree kernel, shared by the block's heads.
         seg = pseg_ref[i]
         assigned = path_ref[0][:, :1] == seg   # (rows, 1)
         for lvl in range(1, depth):
             assigned |= path_ref[lvl][:, :1] == seg
-        s = jnp.where(assigned, s, NEG_INF)
-        _online_update(s, v, acc_scr, m_scr, l_scr)
+        cb = cb_ref[0]   # ragged per-segment tail (0 / NEG_INF), (1, pm)
+        for h in range(hb):
+            k = k_ref[0, h]           # (pm, hd)
+            v = v_ref[0, h]
+            s = jax.lax.dot_general(
+                q_ref[h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                  # (rows, pm)
+            s = s + cb
+            s = jnp.where(assigned, s, NEG_INF)
+            _online_update(s, v, acc_scr.at[h], m_scr.at[h], l_scr.at[h])
 
     @pl.when(i == n_ctx)
     def _decode_arm_and_flush():
-        kd = kd_ref[0]                # (ld, hd)
-        vd = vd_ref[0]
-        sd = jax.lax.dot_general(
-            q, kd, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                      # (rows, ld)
-        sd = sd + bias_ref[...]        # slot validity + ld padding
-        row_s = jax.lax.broadcasted_iota(jnp.int32, sd.shape, 0) // pn
-        col_s = jax.lax.broadcasted_iota(jnp.int32, sd.shape, 1) // c_d
-        sd = jnp.where(row_s == col_s, sd, NEG_INF)
+        for h, kd, vd in _decode_arm_heads(copies, kd_buf, vd_buf, hb):
+            sd = jax.lax.dot_general(
+                q_ref[h], kd, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                  # (rows, ld)
+            sd = sd + bias_ref[...]    # slot validity + ld padding
+            row_s = jax.lax.broadcasted_iota(jnp.int32, sd.shape, 0) // pn
+            col_s = jax.lax.broadcasted_iota(jnp.int32, sd.shape, 1) // c_d
+            sd = jnp.where(row_s == col_s, sd, NEG_INF)
 
-        acc, l_new = _online_update(sd, vd, acc_scr, m_scr, l_scr)
-        out_ref[0] = (acc / jnp.maximum(l_new, 1e-30)).astype(out_ref.dtype)
+            acc, l_new = _online_update(sd, vd, acc_scr.at[h], m_scr.at[h],
+                                        l_scr.at[h])
+            out_ref[h] = (acc / jnp.maximum(l_new, 1e-30)
+                          ).astype(out_ref.dtype)
 
 
 def paged_fused_bifurcated_decode(
@@ -1189,11 +1296,12 @@ def paged_fused_bifurcated_decode(
     head (pm tokens each — page-rounded LIVE length, not padded capacity),
     the b*c_d decode slots, q, the page list/bias, and the output. The
     page walk is driven by scalar-prefetched runtime data, so which pages
-    stream changes per step with ZERO recompiles; grid length is the
-    static page-table envelope (free steps revisit the last live page —
-    no DMA — and skip compute). Same no-spill structure as the dense
-    kernels; bit-identical to ``tree_fused_bifurcated_decode`` on the same
-    logical contents when ``pm`` equals its ``block_m``.
+    stream changes per step with ZERO recompiles; the grid is
+    ``(g // hb, envelope + 1)`` over the static page-table envelope (free
+    steps revisit the last live page — no DMA — and skip compute) with
+    ``hb = walk_head_block(..)`` kv heads per step. Same no-spill structure
+    as the dense kernels; bit-identical to ``tree_fused_bifurcated_decode``
+    on the same logical contents when ``pm`` equals its ``block_m``.
     """
     depth = path_rows.shape[0]
     g, rows, hd = q.shape
@@ -1211,23 +1319,27 @@ def paged_fused_bifurcated_decode(
                            constant_values=NEG_INF)
     ld_full = ld + ld_pad
 
+    shapes = dict(rows=rows, hd=hd, pm=pm, depth=depth, ld=ld_full,
+                  itemsize=k_pages.dtype.itemsize)
+    hb = walk_head_block(g, **shapes)
     kernel = functools.partial(
-        _paged_fused_kernel, scale=scale, c_d=c_d, pn=pn, depth=depth
+        _paged_fused_kernel, scale=scale, c_d=c_d, pn=pn, depth=depth, hb=hb
     )
     last = max_pages - 1
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(g, max_pages + 1),
+        grid=(g // hb, max_pages + 1),
         in_specs=[
-            pl.BlockSpec((1, rows, hd),
+            pl.BlockSpec((hb, rows, hd),
                          lambda gk, i, pid, seg, nl: (gk, 0, 0)),
-            # the page walk: block index = prefetched pool id. During the
-            # decode step (and past n_live) the index pins to the previous
-            # entry, so the revisiting rule skips the DMA.
-            pl.BlockSpec((1, 1, pm, hd),
+            # the page walk: block index = prefetched pool id, hb heads of
+            # the page in one copy. During the decode step (and past
+            # n_live) the index pins to the previous entry, so the
+            # revisiting rule skips the DMA.
+            pl.BlockSpec((1, hb, pm, hd),
                          lambda gk, i, pid, seg, nl:
                          (pid[jnp.minimum(i, last)], gk, 0, 0)),
-            pl.BlockSpec((1, 1, pm, hd),
+            pl.BlockSpec((1, hb, pm, hd),
                          lambda gk, i, pid, seg, nl:
                          (pid[jnp.minimum(i, last)], gk, 0, 0)),
             pl.BlockSpec((depth, rows, 128),
@@ -1235,28 +1347,30 @@ def paged_fused_bifurcated_decode(
             pl.BlockSpec((1, 1, pm),
                          lambda gk, i, pid, seg, nl:
                          (jnp.minimum(i, last), 0, 0)),
-            pl.BlockSpec((1, ld_full, hd),
-                         lambda gk, i, pid, seg, nl: (gk, 0, 0)),
-            pl.BlockSpec((1, ld_full, hd),
-                         lambda gk, i, pid, seg, nl: (gk, 0, 0)),
+            # decode-arm K/V stay in HBM: copied a head at a time
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, ld_full),
                          lambda gk, i, pid, seg, nl: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, rows, hd),
+        out_specs=pl.BlockSpec((hb, rows, hd),
                                lambda gk, i, pid, seg, nl: (gk, 0, 0)),
         scratch_shapes=[
             # fp32 VMEM accumulators — never spilled to HBM; the page walk
             # adds grid steps, not VMEM residency (working set = one page
-            # of K/V + the usual q/decode/stat tiles).
-            pltpu.VMEM((rows, hd), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
+            # of K/V per head of the block + the usual q/decode/stat
+            # tiles, ``walk_vmem_bytes``).
+            pltpu.VMEM((hb, rows, hd), jnp.float32),
+            pltpu.VMEM((hb, rows, 128), jnp.float32),
+            pltpu.VMEM((hb, rows, 128), jnp.float32),
+            *_decode_arm_scratch(ld_full, hd, k_dec.dtype),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((g, rows, hd), q.dtype),
+        compiler_params=_walk_compiler_params(hb, **shapes),
         interpret=interpret,
     )(page_ids, page_segs, n_live,
       q, k_pages, v_pages, path_rows, page_bias, k_dec, v_dec, dec_bias)
@@ -1467,15 +1581,16 @@ def _packed_fused_kernel(
     depth: int,
     has_carry: bool,
     emit_partials: bool,
+    hb: int,
 ):
     """Work-queue generalization of ``_paged_fused_kernel``: grid step i
-    processes queue entry i — a pool page or a fresh prefill tile, selected
-    in-register by ``kind`` while BOTH DMA streams pin their unused side to
-    the previous block (revisiting rule ⇒ one real copy per step). The
-    per-entry op sequence (scale, entry bias, path membership, online
-    update) is the paged kernel's exactly, plus one causal term that is
-    vacuously true for pages — which is what makes a decode-only queue
-    bit-identical.
+    processes queue entry i for a block of ``hb`` kv heads — a pool page or
+    a fresh prefill tile, selected in-register by ``kind`` while BOTH DMA
+    streams pin their unused side to the previous block (revisiting rule ⇒
+    one real copy per step). Each head's per-entry op sequence (scale,
+    entry bias, path membership, online update) is the paged kernel's
+    exactly, plus one causal term that is vacuously true for pages — which
+    is what makes a decode-only queue bit-identical.
 
     ``has_carry`` seeds the fp32 scratch from a previous launch's raw
     (acc, m, l) instead of the identity; ``emit_partials`` flushes raw
@@ -1493,10 +1608,14 @@ def _packed_fused_kernel(
         accout_ref, mout_ref, lout_ref = refs[idx:idx + 3]
         idx += 3
     else:
-        kd_ref, vd_ref, bias_ref = refs[idx:idx + 3]
+        kd_hbm, vd_hbm, bias_ref = refs[idx:idx + 3]
         out_ref = refs[idx + 3]
         idx += 4
     acc_scr, m_scr, l_scr = refs[idx:idx + 3]
+    if not emit_partials:
+        kd_buf, vd_buf, dma_sem = refs[idx + 3:idx + 6]
+        copies = _decode_arm_copies(kd_hbm, vd_hbm, kd_buf, vd_buf, dma_sem,
+                                    pl.program_id(0) * hb)
 
     i = pl.program_id(1)
     n_ctx = pl.num_programs(1) - 1   # queue steps; last = decode arm/flush
@@ -1504,83 +1623,92 @@ def _packed_fused_kernel(
     @pl.when(i == 0)
     def _init():
         if has_carry:
-            acc_scr[...] = acc0_ref[0]
-            m_scr[...] = m0_ref[0]
-            l_scr[...] = l0_ref[0]
+            acc_scr[...] = acc0_ref[...]
+            m_scr[...] = m0_ref[...]
+            l_scr[...] = l0_ref[...]
         else:
             acc_scr[...] = jnp.zeros_like(acc_scr)
             m_scr[...] = jnp.full_like(m_scr, NEG_INF)
             l_scr[...] = jnp.zeros_like(l_scr)
-
-    q = q_ref[0]                      # (rows, hd)
+        if not emit_partials:
+            for c in copies(0):
+                c.start()
 
     @pl.when((i < n_ctx) & (i < nent_ref[0]))
     def _queue_entry():
         is_page = kind_ref[i] == 0
-        # in-register select between the two pinned DMA streams — the
-        # unused one holds the PREVIOUS block (no copy moved for it).
-        k = jnp.where(is_page, k_ref[0, 0], kf_ref[0, 0])   # (pm, hd)
-        v = jnp.where(is_page, v_ref[0, 0], vf_ref[0, 0])
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                      # (rows, pm)
-        s = s + eb_ref[0]              # ragged tail / chunk-length bias
         seg = seg_ref[i]
         assigned = path_ref[0][:, :1] == seg   # (rows, 1)
         for lvl in range(1, depth):
             assigned |= path_ref[lvl][:, :1] == seg
         # causal mask for fresh tiles: entry columns live at absolute
         # positions pos[i]..pos[i]+pm-1 and a row may only attend columns
-        # at-or-before its own position. Pages: vacuously true.
-        cols = pos_ref[i] + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # at-or-before its own position. Pages: vacuously true. Shared by
+        # the block's heads.
+        rows, pm = q_ref.shape[1], k_ref.shape[2]
+        cols = pos_ref[i] + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, pm), 1)
         ok = assigned & (is_page | (cols <= rpos_ref[:, :1]))
-        s = jnp.where(ok, s, NEG_INF)
-        _online_update(s, v, acc_scr, m_scr, l_scr)
+        eb = eb_ref[0]   # ragged tail / chunk-length bias, (1, pm)
+        for h in range(hb):
+            # in-register select between the two pinned DMA streams — the
+            # unused one holds the PREVIOUS block (no copy moved for it).
+            k = jnp.where(is_page, k_ref[0, h], kf_ref[0, h])   # (pm, hd)
+            v = jnp.where(is_page, v_ref[0, h], vf_ref[0, h])
+            s = jax.lax.dot_general(
+                q_ref[h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                  # (rows, pm)
+            s = s + eb
+            s = jnp.where(ok, s, NEG_INF)
+            _online_update(s, v, acc_scr.at[h], m_scr.at[h], l_scr.at[h])
 
     @pl.when(i == n_ctx)
     def _final_step():
         if emit_partials:
-            accout_ref[0] = acc_scr[...]
-            mout_ref[0] = m_scr[...]
-            lout_ref[0] = l_scr[...]
-        else:
-            kd = kd_ref[0]                # (ld, hd)
-            vd = vd_ref[0]
+            accout_ref[...] = acc_scr[...]
+            mout_ref[...] = m_scr[...]
+            lout_ref[...] = l_scr[...]
+            return
+        for h, kd, vd in _decode_arm_heads(copies, kd_buf, vd_buf, hb):
             sd = jax.lax.dot_general(
-                q, kd, (((1,), (1,)), ((), ())),
+                q_ref[h], kd, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ) * scale                      # (rows, ld)
-            sd = sd + bias_ref[...]        # slot validity + ld padding
+            ) * scale                  # (rows, ld)
+            sd = sd + bias_ref[...]    # slot validity + ld padding
             col_s = jax.lax.broadcasted_iota(jnp.int32, sd.shape, 1) // c_d
             # row_slot replaces the paged kernel's iota//pn: decode rows
             # carry their slot id (identical values), chunk rows carry -1
             # (never a valid column slot -> zero contribution).
             sd = jnp.where(rslot_ref[:, :1] == col_s, sd, NEG_INF)
 
-            acc, l_new = _online_update(sd, vd, acc_scr, m_scr, l_scr)
-            out_ref[0] = (
+            acc, l_new = _online_update(sd, vd, acc_scr.at[h], m_scr.at[h],
+                                        l_scr.at[h])
+            out_ref[h] = (
                 acc / jnp.maximum(l_new, 1e-30)
             ).astype(out_ref.dtype)
 
 
 def _packed_specs(
     rows, hd, pm, depth, max_q, ld_full, g,
-    *, has_carry, emit_partials, q8,
+    *, has_carry, emit_partials, q8, hb=1, dec_dtype=None,
 ):
     """Shared BlockSpec scaffolding for the packed kernels. Index-map args
     after the grid indices are the six prefetch refs (kind, seg, pdma,
-    fdma, pos, n_ent)."""
+    fdma, pos, n_ent). The bf16 kernel walks ``hb`` kv heads per grid step
+    and keeps its decode-arm K/V in HBM (copied a head at a time into two
+    ``dec_dtype`` VMEM slots); the q8 kernel walks one head per step with
+    a pipelined decode arm."""
     last = max_q - 1
     in_specs = [
-        pl.BlockSpec((1, rows, hd),
+        pl.BlockSpec((hb, rows, hd),
                      lambda gk, i, kn, sg, pd, fd, ps, ne: (gk, 0, 0)),
         # pool-page walk: block index = prefetched pdma entry. Fresh-tile
         # steps (and the final step) pin to the previous page — no DMA.
-        pl.BlockSpec((1, 1, pm, hd),
+        pl.BlockSpec((1, hb, pm, hd),
                      lambda gk, i, kn, sg, pd, fd, ps, ne:
                      (pd[jnp.minimum(i, last)], gk, 0, 0)),
-        pl.BlockSpec((1, 1, pm, hd),
+        pl.BlockSpec((1, hb, pm, hd),
                      lambda gk, i, kn, sg, pd, fd, ps, ne:
                      (pd[jnp.minimum(i, last)], gk, 0, 0)),
     ]
@@ -1595,10 +1723,10 @@ def _packed_specs(
         ]
     in_specs += [
         # fresh-tile walk: the symmetric pinned stream (bf16 either way).
-        pl.BlockSpec((1, 1, pm, hd),
+        pl.BlockSpec((1, hb, pm, hd),
                      lambda gk, i, kn, sg, pd, fd, ps, ne:
                      (fd[jnp.minimum(i, last)], gk, 0, 0)),
-        pl.BlockSpec((1, 1, pm, hd),
+        pl.BlockSpec((1, hb, pm, hd),
                      lambda gk, i, kn, sg, pd, fd, ps, ne:
                      (fd[jnp.minimum(i, last)], gk, 0, 0)),
         pl.BlockSpec((depth, rows, 128),
@@ -1611,47 +1739,50 @@ def _packed_specs(
         pl.BlockSpec((rows, 128),
                      lambda gk, i, kn, sg, pd, fd, ps, ne: (0, 0)),
     ]
+    state_specs = [
+        pl.BlockSpec((hb, rows, hd),
+                     lambda gk, i, kn, sg, pd, fd, ps, ne: (gk, 0, 0)),
+        pl.BlockSpec((hb, rows, 128),
+                     lambda gk, i, kn, sg, pd, fd, ps, ne: (gk, 0, 0)),
+        pl.BlockSpec((hb, rows, 128),
+                     lambda gk, i, kn, sg, pd, fd, ps, ne: (gk, 0, 0)),
+    ]
     if has_carry:
-        in_specs += [
-            pl.BlockSpec((1, rows, hd),
-                         lambda gk, i, kn, sg, pd, fd, ps, ne: (gk, 0, 0)),
-            pl.BlockSpec((1, rows, 128),
-                         lambda gk, i, kn, sg, pd, fd, ps, ne: (gk, 0, 0)),
-            pl.BlockSpec((1, rows, 128),
-                         lambda gk, i, kn, sg, pd, fd, ps, ne: (gk, 0, 0)),
-        ]
+        in_specs += state_specs
+    heads = () if q8 else (hb,)   # the q8 kernel keeps one head's state
+    scratch_shapes = [
+        pltpu.VMEM(heads + (rows, hd), jnp.float32),
+        pltpu.VMEM(heads + (rows, 128), jnp.float32),
+        pltpu.VMEM(heads + (rows, 128), jnp.float32),
+    ]
     if emit_partials:
-        out_specs = [
-            pl.BlockSpec((1, rows, hd),
-                         lambda gk, i, kn, sg, pd, fd, ps, ne: (gk, 0, 0)),
-            pl.BlockSpec((1, rows, 128),
-                         lambda gk, i, kn, sg, pd, fd, ps, ne: (gk, 0, 0)),
-            pl.BlockSpec((1, rows, 128),
-                         lambda gk, i, kn, sg, pd, fd, ps, ne: (gk, 0, 0)),
-        ]
+        out_specs = state_specs
         out_shape = [
             jax.ShapeDtypeStruct((g, rows, hd), jnp.float32),
             jax.ShapeDtypeStruct((g, rows, 128), jnp.float32),
             jax.ShapeDtypeStruct((g, rows, 128), jnp.float32),
         ]
     else:
-        in_specs += [
-            pl.BlockSpec((1, ld_full, hd),
-                         lambda gk, i, kn, sg, pd, fd, ps, ne: (gk, 0, 0)),
-            pl.BlockSpec((1, ld_full, hd),
-                         lambda gk, i, kn, sg, pd, fd, ps, ne: (gk, 0, 0)),
+        if q8:
+            in_specs += [
+                pl.BlockSpec((1, ld_full, hd),
+                             lambda gk, i, kn, sg, pd, fd, ps, ne:
+                             (gk, 0, 0)),
+                pl.BlockSpec((1, ld_full, hd),
+                             lambda gk, i, kn, sg, pd, fd, ps, ne:
+                             (gk, 0, 0)),
+            ]
+        else:
+            in_specs += [pl.BlockSpec(memory_space=pl.ANY),
+                         pl.BlockSpec(memory_space=pl.ANY)]
+            scratch_shapes += _decode_arm_scratch(ld_full, hd, dec_dtype)
+        in_specs.append(
             pl.BlockSpec((1, ld_full),
-                         lambda gk, i, kn, sg, pd, fd, ps, ne: (0, 0)),
-        ]
+                         lambda gk, i, kn, sg, pd, fd, ps, ne: (0, 0)))
         out_specs = pl.BlockSpec(
-            (1, rows, hd),
+            (hb, rows, hd),
             lambda gk, i, kn, sg, pd, fd, ps, ne: (gk, 0, 0))
         out_shape = None   # caller supplies (needs q.dtype)
-    scratch_shapes = [
-        pltpu.VMEM((rows, hd), jnp.float32),
-        pltpu.VMEM((rows, 128), jnp.float32),
-        pltpu.VMEM((rows, 128), jnp.float32),
-    ]
     return in_specs, out_specs, out_shape, scratch_shapes
 
 
@@ -1704,7 +1835,9 @@ def packed_fused_bifurcated_decode(
     ``carry=(acc, m, l)`` / ``emit_partials=True`` chain launches exactly
     for queues longer than one grid envelope; the chained result is
     bit-identical to a single launch because the raw fp32 state round-trips
-    losslessly and the per-entry op sequence is unchanged."""
+    losslessly and the per-entry op sequence is unchanged. The grid is
+    ``(g // hb, max_q + 1)``, ``hb = walk_head_block(..)`` kv heads per
+    step; each launch of a chain reads its own ``hb`` off its shapes."""
     depth = path_rows.shape[0]
     g, rows, hd = q.shape
     pm = k_pages.shape[2]
@@ -1717,13 +1850,18 @@ def packed_fused_bifurcated_decode(
         k_dec, v_dec, dec_bias, ld_full = _pad_decode_tile(
             k_dec, v_dec, dec_bias)
 
+    shapes = dict(rows=rows, hd=hd, pm=pm, depth=depth, ld=ld_full,
+                  itemsize=k_pages.dtype.itemsize, fresh=True,
+                  carry=carry is not None, partials=emit_partials)
+    hb = walk_head_block(g, **shapes)
     kernel = functools.partial(
         _packed_fused_kernel, scale=scale, c_d=c_d, depth=depth,
-        has_carry=carry is not None, emit_partials=emit_partials,
+        has_carry=carry is not None, emit_partials=emit_partials, hb=hb,
     )
     in_specs, out_specs, out_shape, scratch = _packed_specs(
         rows, hd, pm, depth, max_q, ld_full, g,
         has_carry=carry is not None, emit_partials=emit_partials, q8=False,
+        hb=hb, dec_dtype=None if emit_partials else k_dec.dtype,
     )
     if out_shape is None:
         out_shape = jax.ShapeDtypeStruct((g, rows, hd), q.dtype)
@@ -1737,7 +1875,7 @@ def packed_fused_bifurcated_decode(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
-        grid=(g, max_q + 1),
+        grid=(g // hb, max_q + 1),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,
@@ -1746,6 +1884,7 @@ def packed_fused_bifurcated_decode(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
+        compiler_params=_walk_compiler_params(hb, **shapes),
         interpret=interpret,
     )(ent_kind, ent_seg, ent_pdma, ent_fdma, ent_pos, n_ent, *operands)
 

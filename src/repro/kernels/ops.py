@@ -88,6 +88,7 @@ from repro.kernels.bifurcated_decode import (
     paged_fused_bifurcated_decode_q8,
     tree_fused_bifurcated_decode,
     tree_fused_bifurcated_decode_q8,
+    walk_head_block,
 )
 
 NEG_INF = -1e30
@@ -537,6 +538,30 @@ def live_page_list(page_tables, seg_lens, page_m: int):
     page_bias = jnp.where(cols < valid_to[:, None], 0.0, NEG_INF
                           ).astype(jnp.float32)
     return ids, segs, n_live[None], page_bias
+
+
+def paged_walk_grid(*, b, g, p, n, hd, c_d, pm, envelope, depth, itemsize,
+                    q8=False):
+    """The grid ``paged_bifurcated_decode_attention`` runs for these
+    shapes: (kv-head blocks, page-list entries + the decode-arm step). The
+    bf16 kernel walks ``walk_head_block`` heads per step, the q8 twin one.
+    ``envelope`` is the page-table size N * ppn, ``itemsize`` the pool's."""
+    shapes = dict(rows=b * p * n, hd=hd, pm=pm, depth=depth,
+                  ld=-(-b * c_d // 128) * 128, itemsize=itemsize)
+    hb = 1 if q8 else walk_head_block(g, **shapes)
+    return g // hb, envelope + 1
+
+
+def packed_walk_grid(*, b, g, p, n, hd, c_d, pm, envelope, depth, itemsize,
+                     chunk, fresh_tiles, q8=False):
+    """The grid of one ``packed_bifurcated_decode_attention`` launch for
+    these shapes: ``paged_walk_grid`` with the chunk's ``chunk * p`` query
+    rows, the pseudo-segment path level and ``fresh_tiles`` fresh-KV
+    entries added."""
+    shapes = dict(rows=b * p * n + chunk * p, hd=hd, pm=pm, depth=depth + 1,
+                  ld=-(-b * c_d // 128) * 128, itemsize=itemsize, fresh=True)
+    hb = 1 if q8 else walk_head_block(g, **shapes)
+    return g // hb, envelope + fresh_tiles + 1
 
 
 def _paged_operands(q, paths, k_dec, v_dec, dec_mask):

@@ -385,6 +385,33 @@ class _SlotTableEngine:
         # quarantines the owning request through the cancel path.
         self.seg_checksums = {}     # segment/node id -> crc32 at write
         self.corrupt_slots = set()  # slots that emitted non-finite output
+        self._walk = {}             # packed? -> page-walk span attribute
+
+    def _walk_steps(self, cache, packed: bool = False) -> dict:
+        """``{"walk_steps": n}`` for a step's span: the grid steps of one
+        layer's page-walk kernel call, (kv-head blocks) x (page-list
+        entries + 1), read off the cache's shapes; empty where the step
+        walks no page list (dense store or einsum reference)."""
+        if not (self.ecfg.use_kernel and getattr(self, "paged", False)):
+            return {}
+        if packed not in self._walk:
+            from repro.kernels import ops
+
+            store = cache.store
+            _, _, g, pm, hd = store.k_pages.shape
+            n_seg, ppn = store.page_tables.shape
+            kw = dict(b=cache.k_dec.shape[1], g=g,
+                      p=self.cfg.n_heads_padded // g, n=1, hd=hd,
+                      c_d=cache.k_dec.shape[2], pm=pm, envelope=n_seg * ppn,
+                      depth=cache.paths.shape[0] if hasattr(cache, "paths")
+                      else 1,
+                      itemsize=store.k_pages.dtype.itemsize,
+                      q8=hasattr(store, "k_scale_pages"))
+            grid = (ops.packed_walk_grid(**kw, chunk=self._prefill_chunk(),
+                                         fresh_tiles=self.pages_per_node)
+                    if packed else ops.paged_walk_grid(**kw))
+            self._walk[packed] = {"walk_steps": grid[0] * grid[1]}
+        return self._walk[packed]
 
     # ---- decode ----
     def _decode_one(self, params, state: ForestState):
@@ -431,7 +458,8 @@ class _SlotTableEngine:
         sit at different depths; the guard tracks the deepest live one.)"""
         import numpy as np
 
-        with span("engine.scan", n_steps=n_steps) as sp:
+        with span("engine.scan", n_steps=n_steps,
+                  **self._walk_steps(state.cache)) as sp:
             with span("engine.inputs"):
                 active = np.asarray(state.active)
                 if active.any():
@@ -1614,7 +1642,8 @@ class TreeServeEngine(_SlotTableEngine):
         cv = min(self._prefill_chunk(), m_node - pend["buf_len"])
         chunk = pend["suffix"][pend["done"]:pend["done"] + cv]
 
-        with span("engine.packed_step", rid=rid, prefill_tokens=cv) as sp:
+        with span("engine.packed_step", rid=rid, prefill_tokens=cv,
+                  **self._walk_steps(state.cache, packed=True)) as sp:
             with span("engine.inputs"):
                 active = np.asarray(state.active)
                 if active.any():

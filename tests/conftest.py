@@ -5,8 +5,10 @@ import sys
 # launch/dryrun.py, per the brief).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
+import pytest  # noqa: E402
 
 try:  # optional dep: CI installs it, local runs may not have it
     from hypothesis import settings as _hyp_settings
@@ -115,6 +117,30 @@ def collect_pallas_calls(jaxpr):
             elif isinstance(v, Jaxpr):
                 calls += collect_pallas_calls(v)
     return calls
+
+
+def pallas_grids(fn, *args, **kwargs):
+    """The grid of every pallas_call that ``fn(*args, **kwargs)`` traces."""
+    import functools
+
+    jaxpr = jax.make_jaxpr(functools.partial(fn, **kwargs))(*args)
+    return [tuple(c.params["grid_mapping"].grid)
+            for c in collect_pallas_calls(jaxpr.jaxpr)]
+
+
+@pytest.fixture
+def walk_budget(monkeypatch):
+    """Set the head-blocked page walk's VMEM budget for one test. Jit
+    caches are cleared when it is set and when the test ends, so no
+    program traced under another budget is reused on either side."""
+    from repro.kernels import bifurcated_decode
+
+    def set_budget(nbytes):
+        monkeypatch.setattr(bifurcated_decode, "WALK_VMEM_BUDGET", nbytes)
+        jax.clear_caches()
+
+    yield set_budget
+    jax.clear_caches()
 
 
 def assert_no_hbm_spill(jaxpr, *, out_dtype, hd=None, q8=False,

@@ -38,10 +38,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import assert_no_hbm_spill, build_page_pool, make_decode_case
+from conftest import (
+    assert_no_hbm_spill,
+    build_page_pool,
+    make_decode_case,
+    pallas_grids,
+)
 from repro.configs import TreeConfig, get_config, reduced_config
 from repro.core.errors import PrefillInFlight
 from repro.core.quantized import quantize_ctx
+from repro.kernels.bifurcated_decode import walk_vmem_bytes
 from repro.kernels.ops import (
     packed_bifurcated_decode_attention,
     packed_bifurcated_decode_attention_q8,
@@ -195,28 +201,29 @@ def test_packed_dispatch_zero_recompiles():
 # Packed kernel: chunk oracle, no-spill, multi-launch with a chunk
 # ---------------------------------------------------------------------------
 
-def _chunk_case(seed=0, m_anc=24, cp=6, buf=3, b=2, c_d=4, pm=8, fcap=2):
+def _chunk_case(seed=0, m_anc=24, cp=6, buf=3, b=2, c_d=4, pm=8, fcap=2,
+                g=G):
     """One packed step mid-prefill: b decode rows over the ancestor
     segment + a cp-row chunk at absolute offset m_anc + buf whose fresh
     envelope holds buf + cp live columns."""
     rng = np.random.RandomState(seed)
     f = lambda *s: rng.randn(*s).astype(np.float32)
     case = {
-        "q": jnp.asarray(f(b, G, 1, 1, HD), jnp.float32),
-        "kd": jnp.asarray(f(b, c_d, G, HD), jnp.float32),
-        "vd": jnp.asarray(f(b, c_d, G, HD), jnp.float32),
+        "q": jnp.asarray(f(b, g, 1, 1, HD), jnp.float32),
+        "kd": jnp.asarray(f(b, c_d, g, HD), jnp.float32),
+        "vd": jnp.asarray(f(b, c_d, g, HD), jnp.float32),
         "mask": jnp.ones((b, c_d), bool),
-        "kc": jnp.asarray(f(m_anc, G, HD), jnp.float32),
-        "vc": jnp.asarray(f(m_anc, G, HD), jnp.float32),
+        "kc": jnp.asarray(f(m_anc, g, HD), jnp.float32),
+        "vc": jnp.asarray(f(m_anc, g, HD), jnp.float32),
     }
     fresh_len = buf + cp
-    kf_live = f(fresh_len, G, HD)
-    vf_live = f(fresh_len, G, HD)
-    kf = np.zeros((fcap * pm, G, HD), np.float32)
+    kf_live = f(fresh_len, g, HD)
+    vf_live = f(fresh_len, g, HD)
+    kf = np.zeros((fcap * pm, g, HD), np.float32)
     vf = np.zeros_like(kf)
     kf[:fresh_len], vf[:fresh_len] = kf_live, vf_live
     case.update(
-        q_fresh=jnp.asarray(f(cp, G, 1, HD), jnp.float32),
+        q_fresh=jnp.asarray(f(cp, g, 1, HD), jnp.float32),
         k_fresh=jnp.asarray(kf), v_fresh=jnp.asarray(vf),
         fresh_len=fresh_len, fresh_start=m_anc,
         fresh_pos=jnp.asarray(m_anc + buf + np.arange(cp), jnp.int32),
@@ -241,10 +248,10 @@ def _pool(case, q8=False):
 def _chunk_oracle(case):
     """NumPy single-pass softmax for each chunk row over
     [ancestors ⊕ causally-visible fresh columns]."""
-    cp = case["q_fresh"].shape[0]
+    cp, g_all = case["q_fresh"].shape[:2]
     m_anc, fl = case["fresh_start"], case["fresh_len"]
     scale = HD**-0.5
-    out = np.zeros((cp, G, 1, HD), np.float32)
+    out = np.zeros((cp, g_all, 1, HD), np.float32)
     kc, vc = np.asarray(case["kc"]), np.asarray(case["vc"])
     kf = np.asarray(case["k_fresh"])[:fl]
     vf = np.asarray(case["v_fresh"])[:fl]
@@ -254,7 +261,7 @@ def _chunk_oracle(case):
     for i in range(cp):
         rp = int(case["fresh_pos"][i])
         vis = pos <= rp
-        for g in range(G):
+        for g in range(g_all):
             qi = np.asarray(case["q_fresh"])[i, g, 0]
             s = (K[vis, g] @ qi) * scale
             w = np.exp(s - s.max())
@@ -263,44 +270,79 @@ def _chunk_oracle(case):
     return out
 
 
-def test_packed_chunk_matches_oracle():
-    case = _chunk_case()
-    (kp, vp), table = _pool(case)
-    seg_lens = jnp.asarray([case["kc"].shape[0]], jnp.int32)
-    paths = jnp.zeros((1, 2), jnp.int32)
-    _, out_fresh = packed_bifurcated_decode_attention(
-        case["q"], kp, vp, table, seg_lens, paths,
-        case["kd"], case["vd"], case["mask"],
+# kv heads x the head block the VMEM budget is cut to (None: not cut). The
+# packed kernel walks all g heads per grid step at these shapes; cut to
+# fit two, 8 heads take four blocks.
+HEAD_BLOCKS = pytest.mark.parametrize("g,budget_hb", [
+    (G, None), (1, None), (8, None), (8, 2),
+], ids=["g2", "g1", "g8", "g8-budget-hb2"])
+
+
+def _cut_budget(walk_budget, case, hb, **kw):
+    """Budget for which a single packed launch over ``case`` walks ``hb``
+    heads per step (chained launches, which hold more, walk fewer)."""
+    cp = case["q_fresh"].shape[0]
+    rows = case["q"].shape[0] + cp
+    walk_budget(walk_vmem_bytes(
+        hb, rows=rows, hd=HD, pm=case["pm"], depth=2, ld=128,
+        itemsize=case["q"].dtype.itemsize, fresh=True, **kw))
+
+
+def _chunk_kwargs(case):
+    return dict(
         q_fresh=case["q_fresh"], k_fresh=case["k_fresh"],
         v_fresh=case["v_fresh"], fresh_len=jnp.int32(case["fresh_len"]),
         fresh_start=jnp.int32(case["fresh_start"]),
         fresh_pos=case["fresh_pos"],
         fresh_path=jnp.asarray([0], jnp.int32), interpret=True)
+
+
+@HEAD_BLOCKS
+def test_packed_chunk_matches_oracle(walk_budget, g, budget_hb):
+    case = _chunk_case(g=g)
+    (kp, vp), table = _pool(case)
+    seg_lens = jnp.asarray([case["kc"].shape[0]], jnp.int32)
+    paths = jnp.zeros((1, 2), jnp.int32)
+    args = (case["q"], kp, vp, table, seg_lens, paths,
+            case["kd"], case["vd"], case["mask"])
+    hb = g
+    if budget_hb is not None:
+        _cut_budget(walk_budget, case, budget_hb)
+        hb = budget_hb
+    envelope = table.size + case["k_fresh"].shape[0] // case["pm"]
+    assert pallas_grids(packed_bifurcated_decode_attention, *args,
+                        **_chunk_kwargs(case)) == [(g // hb, envelope + 1)]
+    _, out_fresh = packed_bifurcated_decode_attention(
+        *args, **_chunk_kwargs(case))
     np.testing.assert_allclose(np.asarray(out_fresh), _chunk_oracle(case),
                                rtol=2e-5, atol=2e-5)
 
 
-def test_packed_multi_launch_with_chunk_bit_identical():
+@HEAD_BLOCKS
+def test_packed_multi_launch_with_chunk_bit_identical(walk_budget, g,
+                                                      budget_hb):
     """Chained launches that SPLIT the queue mid-chunk (pages in one
     launch, fresh tiles in the next) reproduce the single launch
-    bit-for-bit — raw fp32 state round-trips losslessly."""
-    case = _chunk_case(m_anc=24, cp=6, buf=3)
+    bit-for-bit — raw fp32 state round-trips losslessly. Under a cut
+    budget the chained launches, carry and all, walk fewer heads per
+    step than the single launch (all g) they are compared with."""
+    case = _chunk_case(m_anc=24, cp=6, buf=3, g=g)
     (kp, vp), table = _pool(case)
     seg_lens = jnp.asarray([24], jnp.int32)
     paths = jnp.zeros((1, 2), jnp.int32)
-    kw = dict(
-        q_fresh=case["q_fresh"], k_fresh=case["k_fresh"],
-        v_fresh=case["v_fresh"], fresh_len=jnp.int32(case["fresh_len"]),
-        fresh_start=jnp.int32(case["fresh_start"]),
-        fresh_pos=case["fresh_pos"],
-        fresh_path=jnp.asarray([0], jnp.int32), interpret=True)
-    one = packed_bifurcated_decode_attention(
-        case["q"], kp, vp, table, seg_lens, paths,
-        case["kd"], case["vd"], case["mask"], **kw)
+    args = (case["q"], kp, vp, table, seg_lens, paths,
+            case["kd"], case["vd"], case["mask"])
+    kw = _chunk_kwargs(case)
+    one = packed_bifurcated_decode_attention(*args, **kw)
+    if budget_hb is not None:
+        _cut_budget(walk_budget, case, budget_hb, carry=True)
+        grids = pallas_grids(packed_bifurcated_decode_attention, *args,
+                             entries_per_launch=2, **kw)
+        assert len(grids) == 3                      # 5 entries, 2 a launch
+        assert all(gk > 1 for gk, _ in grids), grids   # hb < g in each
+        assert grids[-1][0] == g // budget_hb, grids
     two = packed_bifurcated_decode_attention(
-        case["q"], kp, vp, table, seg_lens, paths,
-        case["kd"], case["vd"], case["mask"],
-        entries_per_launch=2, **kw)
+        *args, entries_per_launch=2, **kw)
     for a, b in zip(one, two):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 

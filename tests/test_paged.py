@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import assert_no_hbm_spill, build_page_pool
+from conftest import assert_no_hbm_spill, build_page_pool, pallas_grids
 from repro.core.paged import (
     PageAllocator,
     PagedBifurcatedCache,
@@ -44,6 +44,7 @@ from repro.core.paged import (
     pages_needed,
 )
 from repro.core.quantized import quantize_ctx
+from repro.kernels.bifurcated_decode import walk_vmem_bytes
 from repro.kernels.ops import (
     live_page_list,
     paged_bifurcated_decode_attention,
@@ -61,7 +62,7 @@ G, HD, PM = 2, 32, 64
 
 def make_paged_trie(node_lens, paths_cols, *, b=None, c_d=8, page_m=PM,
                     node_capacity=None, seed=0, dtype=jnp.bfloat16,
-                    extra_pages=2):
+                    extra_pages=2, g=G):
     """Build one decode problem over a ragged trie twice: dense "gmk" node
     segments (zero-padded to capacity) and a page pool holding the SAME
     logical contents on shuffled pool pages (conftest.build_page_pool)."""
@@ -72,11 +73,11 @@ def make_paged_trie(node_lens, paths_cols, *, b=None, c_d=8, page_m=PM,
     cap = pages_needed(node_capacity, page_m) * page_m
     b = b or len(paths_cols)
 
-    kc = np.zeros((n_nodes, G, cap, HD), np.float32)
+    kc = np.zeros((n_nodes, g, cap, HD), np.float32)
     vc = np.zeros_like(kc)
     for i, m in enumerate(node_lens):
-        kc[i, :, :m] = rng.randn(G, m, HD)
-        vc[i, :, :m] = rng.randn(G, m, HD)
+        kc[i, :, :m] = rng.randn(g, m, HD)
+        vc[i, :, :m] = rng.randn(g, m, HD)
     kc, vc = jnp.asarray(kc, dtype), jnp.asarray(vc, dtype)
     # q8 twins: quantize the DENSE segments, then page values + scales
     kq, ks = quantize_ctx(kc, fold_scale=HD**-0.5)
@@ -91,9 +92,9 @@ def make_paged_trie(node_lens, paths_cols, *, b=None, c_d=8, page_m=PM,
         "kpq": kpq, "vpq": vpq, "ksp": ksp, "vsp": vsp,
         "tables": tables,
         "nlens": jnp.asarray(node_lens, jnp.int32),
-        "q": jnp.asarray(rng.randn(b, G, 1, 1, HD), dtype),
-        "kd": jnp.asarray(rng.randn(b, c_d, G, HD), dtype),
-        "vd": jnp.asarray(rng.randn(b, c_d, G, HD), dtype),
+        "q": jnp.asarray(rng.randn(b, g, 1, 1, HD), dtype),
+        "kd": jnp.asarray(rng.randn(b, c_d, g, HD), dtype),
+        "vd": jnp.asarray(rng.randn(b, c_d, g, HD), dtype),
         "mask": jnp.arange(c_d)[None, :] < jnp.asarray(
             rng.randint(1, c_d + 1, size=(b,)))[:, None],
         "page_m": page_m, "cap": cap,
@@ -218,20 +219,38 @@ def test_live_page_list_bias_masks_ragged_tails():
 # Kernel exactness: bit-identical to the dense tree kernels
 # ---------------------------------------------------------------------------
 
-def test_paged_kernel_bit_identical_to_dense_tree():
+@pytest.mark.parametrize("g,budget_hb", [
+    (G, None), (1, None), (8, None), (8, 2),
+], ids=["g2", "g1", "g8", "g8-budget-hb2"])
+def test_paged_kernel_bit_identical_to_dense_tree(walk_budget, g, budget_hb):
     """ISSUE acceptance: on the same logical contents (ragged lengths,
     permuted pool pages, a FREE node) the paged kernel's output is
     BIT-identical to the dense tree kernel at block_m == page_m — the
     skipped blocks' contributions are exact zeros (or pre-first-column
-    state wiped by the corr == 0 rescale), both dtypes."""
-    case = make_paged_trie(**RAGGED)
+    state wiped by the corr == 0 rescale), both dtypes.
+
+    The bf16 paged kernel walks a block of kv heads per grid step (all g
+    of them at these shapes; 2 of 8 where the VMEM budget is cut to fit
+    exactly two) while the tree kernel takes one head per step: each
+    head's arithmetic is unchanged, so the results agree bit for bit."""
+    case = make_paged_trie(**RAGGED, g=g)
+    args = (case["q"], case["kp"], case["vp"], case["tables"],
+            case["nlens"], case["paths"], case["kd"], case["vd"],
+            case["mask"])
+    hb = g
+    if budget_hb is not None:
+        walk_budget(walk_vmem_bytes(
+            budget_hb, rows=case["q"].shape[0], hd=HD, pm=case["page_m"],
+            depth=case["paths"].shape[0], ld=128, itemsize=2))
+        hb = budget_hb
+    envelope = case["tables"].size
+    assert pallas_grids(paged_bifurcated_decode_attention, *args,
+                        interpret=True) == [(g // hb, envelope + 1)]
     out_d = tree_bifurcated_decode_attention(
         case["q"], case["kc"], case["vc"], case["paths"], case["nlens"],
         case["kd"], case["vd"], case["mask"],
         block_m=case["page_m"], interpret=True, ctx_layout="gmk")
-    out_p = paged_bifurcated_decode_attention(
-        case["q"], case["kp"], case["vp"], case["tables"], case["nlens"],
-        case["paths"], case["kd"], case["vd"], case["mask"], interpret=True)
+    out_p = paged_bifurcated_decode_attention(*args, interpret=True)
     np.testing.assert_array_equal(np.asarray(out_p), np.asarray(out_d))
 
     out_dq = tree_bifurcated_decode_attention_q8(
@@ -243,6 +262,40 @@ def test_paged_kernel_bit_identical_to_dense_tree():
         case["tables"], case["nlens"], case["paths"],
         case["kd"], case["vd"], case["mask"], interpret=True)
     np.testing.assert_array_equal(np.asarray(out_pq), np.asarray(out_dq))
+
+
+def test_walk_head_block_divides_fits_and_takes_the_most():
+    """The head block divides g, its working set fits the VMEM budget,
+    the next divisor up does not (or nothing fits and it is 1), g = 1
+    walks one head; and the serving benchmark's shapes (internlm2-1.8b's
+    8 kv heads of 128, 64 slots x 64 decode tokens, 32 nodes of 16 pages
+    of 128, depth 2, a 128-token chunk) walk all 8 heads per grid step in
+    both the scan's paged call and the packed call (the q8 twin: one)."""
+    from repro.kernels.bifurcated_decode import (
+        WALK_VMEM_BUDGET, walk_head_block)
+    from repro.kernels.ops import packed_walk_grid, paged_walk_grid
+
+    seen = set()
+    for g in (1, 2, 3, 4, 6, 8, 16):
+        for rows, ld in ((5, 128), (128, 4096), (384, 4096), (4096, 128),
+                         (2048, 16384)):
+            shapes = dict(rows=rows, hd=128, pm=128, depth=3, ld=ld,
+                          itemsize=2, fresh=True)
+            hb = walk_head_block(g, **shapes)
+            seen.add((g, hb))
+            assert g % hb == 0
+            assert (hb == 1
+                    or walk_vmem_bytes(hb, **shapes) <= WALK_VMEM_BUDGET)
+            assert all(walk_vmem_bytes(d, **shapes) > WALK_VMEM_BUDGET
+                       for d in range(hb + 1, g + 1) if g % d == 0)
+    assert {hb for g, hb in seen if g == 1} == {1}
+    assert any(1 < hb < g for g, hb in seen)     # a budget-cut block
+
+    cell = dict(b=64, g=8, p=2, n=1, hd=128, c_d=64, pm=128,
+                envelope=32 * 16, depth=2, itemsize=2)
+    assert paged_walk_grid(**cell) == (1, 513)
+    assert packed_walk_grid(**cell, chunk=128, fresh_tiles=16) == (1, 529)
+    assert paged_walk_grid(**cell, q8=True) == (8, 513)
 
 
 def test_paged_kernel_vs_concat_oracle():

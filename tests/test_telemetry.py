@@ -10,6 +10,8 @@ steps whose prefill tokens add up to each admission's suffix. Also: the
 ring's bound and drop count, a recompile recorded under the span that
 caused it, and served tokens bit-identical with and without tracing.
 """
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -156,6 +158,39 @@ def test_span_tree_under_the_profiler(traced):
         assert t.first_token_wall is not None
         assert t.submit_wall <= t.first_token_wall <= t.finish_wall
     assert fe.metrics()["ttft_s"]["p50"] > 0
+
+
+def test_step_spans_carry_the_page_walk_grid(traced):
+    """``engine.scan`` and ``engine.packed_step`` carry ``walk_steps``, the
+    grid steps of one layer's page-walk kernel call. At this engine's
+    shapes both kv heads are one block: 8 nodes x 4 pages + the
+    decode-arm step in a scan, 4 fresh tiles more in a packed step."""
+    _, _, spans = traced
+    walks = {}
+    for s in spans:
+        if s.name in ("engine.scan", "engine.packed_step"):
+            walks.setdefault(s.name, set()).add(s.attrs["walk_steps"])
+    assert walks == {"engine.scan": {33}, "engine.packed_step": {37}}
+
+
+def test_walk_steps_at_the_benchmark_shapes():
+    """At the serving benchmark's engine (internlm2-1.8b, 64 slots x 64
+    decode tokens, 32 nodes of 16 pages of 128) one grid step takes all 8
+    kv heads: 513 steps a layer in a scan, 529 in a packed step. The
+    einsum reference walks no page list and reports none."""
+    cfg = get_config("internlm2-1.8b")
+    tcfg = TreeConfig(
+        slots=64, n_nodes=32, depth=2, node_capacity=2048,
+        decode_capacity=64, page_size=128, num_pages=240, ctx_store="paged",
+        use_kernel=True, step_mode="packed", prefix_cache=True,
+        suffix_prefill=True)
+    eng = TreeServeEngine(get_model(cfg), cfg, tcfg)
+    cache = jax.eval_shape(eng.init_state).cache
+    assert eng._walk_steps(cache) == {"walk_steps": 513}
+    assert eng._walk_steps(cache, packed=True) == {"walk_steps": 529}
+    ref = TreeServeEngine(get_model(cfg), cfg,
+                          dataclasses.replace(tcfg, use_kernel=False))
+    assert ref._walk_steps(cache) == {}
 
 
 def test_ring_bound_and_drop_count(monkeypatch, tmp_path):

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from conftest import pallas_grids
 from repro.kernels import ops
 
 G, P_Q, HD = 8, 2, 128          # kv heads, query heads per kv head, head_dim
@@ -131,3 +132,33 @@ def test_packed_kernel_compiles_for_v5e(sds, q8, pm, chunk):
     fn = (ops.packed_bifurcated_decode_attention_q8 if q8
           else ops.packed_bifurcated_decode_attention)
     assert _compile_has_kernel(fn, *_paged_args(q8, pm, sds, chunk=chunk))
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["paged", "packed"])
+def test_head_blocked_walk_compiles_at_the_benchmark_shapes(sds, packed):
+    """The serving benchmark's decode attention (64 slots x 64 decode
+    tokens, 32 trie nodes x 16 pages of 128 in a 240-page pool, depth 2,
+    a 128-token prefill chunk in packed steps) lowers for the v5e with
+    all 8 kv heads walked per grid step: grid (g // hb, envelope + 1)."""
+    bf, i32 = jnp.bfloat16, jnp.int32
+    slots, c_d, n_nodes, ppn, pm, chunk, depth = 64, 64, 32, 16, 128, 128, 2
+    q = sds((slots, G, P_Q, 1, HD), bf)
+    kd = sds((slots, c_d, G, HD), bf)
+    pages = sds((240, G, pm, HD), bf)
+    args = (q, pages, pages, sds((n_nodes, ppn), i32), sds((n_nodes,), i32),
+            sds((depth, slots), i32), kd, kd, sds((slots, c_d), jnp.bool_))
+    shapes = dict(b=slots, g=G, p=P_Q, n=1, hd=HD, c_d=c_d, pm=pm,
+                  envelope=n_nodes * ppn, depth=depth, itemsize=2)
+    if packed:
+        fresh = sds((ppn * pm, G, HD), bf)
+        args += (sds((chunk, G, P_Q, HD), bf), fresh, fresh, sds((), i32),
+                 sds((), i32), sds((chunk,), i32), sds((depth,), i32))
+        fn = ops.packed_bifurcated_decode_attention
+        grid = ops.packed_walk_grid(**shapes, chunk=chunk, fresh_tiles=ppn)
+        assert grid == (1, n_nodes * ppn + ppn + 1)
+    else:
+        fn = ops.paged_bifurcated_decode_attention
+        grid = ops.paged_walk_grid(**shapes)
+        assert grid == (1, n_nodes * ppn + 1)
+    assert pallas_grids(fn, *args, interpret=False) == [grid]
+    assert _compile_has_kernel(fn, *args)
